@@ -77,8 +77,15 @@ def rff_thompson(key, state, candidates, q, kind="matern52", n_features=512):
     b = jax.random.uniform(k_b, (n_features,), dtype=jnp.float32, maxval=2.0 * jnp.pi)
     scale = jnp.sqrt(2.0 * amp / n_features)
 
+    # Full f32 precision on both scoring matmuls.  At the TPU's default
+    # precision (bf16 passes) the compiled sharded and one-device steps
+    # scored the same candidates up to 6e-3 apart (scores near -6) — more
+    # than the gap between a draw's two best candidates — so 2 of 1024
+    # draws picked different candidates (v5e 2x2, PERF.md).
+    hi = jax.lax.Precision.HIGHEST
+
     def features(x):
-        return scale * jnp.cos(x @ w.T + b[None, :])
+        return scale * jnp.cos(jnp.matmul(x, w.T, precision=hi) + b[None, :])
 
     y_norm = (state.y - state.y_mean) / state.y_std * state.mask
     phi = features(state.x) * state.mask[:, None]  # (n_pad, F)
@@ -95,7 +102,7 @@ def rff_thompson(key, state, candidates, q, kind="matern52", n_features=512):
     delta = jax.scipy.linalg.solve_triangular(chol_a.T, eps, lower=False)
     thetas = theta_mean[:, None] + jnp.sqrt(ridge) * delta  # (F, q)
 
-    scores = features(candidates) @ thetas  # (m, q)
+    scores = jnp.matmul(features(candidates), thetas, precision=hi)  # (m, q)
     return jnp.argmin(scores, axis=0)  # minimization: best draw per sample
 
 

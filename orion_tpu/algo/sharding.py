@@ -215,3 +215,65 @@ def mesh_health_fields(mesh, *arrays):
         fields["mesh_util_min_frac"] = float(lo)
         fields["mesh_util_max_frac"] = float(hi)
     return fields
+
+
+# --------------------------------------------------------------------------
+# Sharded-vs-one-device comparison.  XLA promises no reduction order across
+# shards, so a sharded round's fit and scores may differ from the one-device
+# round in the last bits (observed on the 8-device CPU mesh: 1.5e-8 to
+# 6e-8 in unit-cube rows).  The contract is the same SELECTION with rows
+# equal to within a stated f32 tolerance, never bit equality.
+
+#: Max |difference| allowed between a sharded round's unit-cube rows and
+#: the one-device round's: 1e-5 is 84 f32 ulps at 1.0 — far above
+#: reduction-order noise, far below the distance between two distinct
+#: candidates (a different selection moves a row by ~1e-2 or more).
+ROWS_ATOL = 1e-5
+
+
+def compare_rows(rows_a, rows_b, atol=ROWS_ATOL):
+    """Compare two suggestion batches of unit-cube rows.
+
+    Returns ``{"same_selection", "max_abs_diff", "atol", "ok"}``:
+    ``same_selection`` means row i of one batch is the nearest row of the
+    other batch to row i of this one, for every i (the same candidates
+    picked in the same order); ``ok`` adds ``max_abs_diff <= atol``."""
+    a = np.asarray(rows_a, dtype=np.float64)
+    b = np.asarray(rows_b, dtype=np.float64)
+    if a.shape != b.shape:
+        return {"same_selection": False, "max_abs_diff": None, "atol": atol,
+                "ok": False}
+    max_abs = float(np.max(np.abs(a - b))) if a.size else 0.0
+    d2 = (
+        np.sum(a * a, axis=1)[:, None]
+        + np.sum(b * b, axis=1)[None, :]
+        - 2.0 * (a @ b.T)
+    )
+    same = bool(np.array_equal(np.argmin(d2, axis=1), np.arange(len(a))))
+    return {"same_selection": same, "max_abs_diff": max_abs, "atol": atol,
+            "ok": same and max_abs <= atol}
+
+
+#: Relative tolerance for a sharded round's GP state (posterior weights
+#: ``alpha`` and the packed health vector) against the one-device round's:
+#: f32 reduction-order noise, far below any change of fit.
+STATE_RTOL = 1e-5
+
+
+def compare_states(state_a, state_b, rtol=STATE_RTOL, atol=1e-6):
+    """Compare two rounds' GP states (``GPState``: ``alpha`` and
+    ``health``).  Returns ``{"alpha_max_abs_diff", "health_max_abs_diff",
+    "rtol", "ok"}``; ``ok`` is ``np.allclose(rtol, atol)`` on both."""
+    out = {"rtol": rtol, "ok": True}
+    for name in ("alpha", "health"):
+        a = np.asarray(getattr(state_a, name), dtype=np.float64)
+        b = np.asarray(getattr(state_b, name), dtype=np.float64)
+        same_shape = a.shape == b.shape
+        out[f"{name}_max_abs_diff"] = (
+            float(np.max(np.abs(a - b))) if same_shape and a.size else None
+        )
+        out["ok"] = out["ok"] and same_shape and bool(
+            np.allclose(a, b, rtol=rtol, atol=atol)
+        )
+    return out
+

@@ -52,8 +52,10 @@ def add_subparser(subparsers):
         type=int,
         default=1,
         help="run this many asynchronous workers against the shared storage "
-        "(this process plus N-1 spawned ones; same semantics as launching "
-        "the identical hunt command N times)",
+        "(this process plus N-1 spawned ones).  One process owns the "
+        "device: the spawned workers never load it (JAX_PLATFORMS=cpu) and "
+        "suggest through a gateway this process serves (or the one "
+        "ORION_SERVE_ADDRESS already names)",
     )
     group.add_argument(
         "--profile",
@@ -77,7 +79,14 @@ _SPAWNED_ENV = "ORION_TPU_SPAWNED_WORKER"
 def _spawn_workers(args, experiment):
     """N-1 child processes running the identical hunt (the reference's
     'submit the same command N times' cluster recipe, built in).  The
-    experiment is built/branched BEFORE spawning so children resume it."""
+    experiment is built/branched BEFORE spawning so children resume it.
+
+    A chip belongs to one process, so the children never load it: they run
+    with ``JAX_PLATFORMS=cpu`` (the space codec still runs jnp on the host
+    side) and their algorithms are gateway tenants — of the gateway the
+    experiment is already configured with, else of one this process
+    serves in-process on localhost.  Returns ``(processes, gateway)``;
+    ``gateway`` is None when the experiment already had one."""
     from orion_tpu.storage.documents import MemoryDB
     from orion_tpu.utils.exceptions import CheckError
 
@@ -88,6 +97,7 @@ def _spawn_workers(args, experiment):
             "per-process."
         )
     import os
+    import secrets
     import subprocess
 
     argv = list(getattr(args, "_argv", []) or [])
@@ -101,10 +111,33 @@ def _spawn_workers(args, experiment):
         )
     env = dict(os.environ)
     env[_SPAWNED_ENV] = "1"
-    return [
-        subprocess.Popen([sys.executable, "-m", "orion_tpu.cli", *argv], env=env)
-        for _ in range(args.n_workers - 1)
-    ]
+    env["JAX_PLATFORMS"] = "cpu"
+    gateway = None
+    if not experiment.serve_config:
+        from orion_tpu.serve.gateway import GatewayServer
+
+        secret = secrets.token_hex(16)
+        gateway = GatewayServer(host="127.0.0.1", port=0, secret=secret)
+        host, port = gateway.serve_background()
+        env["ORION_SERVE_ADDRESS"] = f"{host}:{port}"
+        env["ORION_SERVE_SECRET"] = secret
+        for key in ("ORION_SERVE_ADDRESSES", "ORION_SERVE_SECRET_FILE"):
+            env.pop(key, None)
+    try:
+        procs = [
+            subprocess.Popen([sys.executable, "-m", "orion_tpu.cli", *argv], env=env)
+            for _ in range(args.n_workers - 1)
+        ]
+    except BaseException:
+        _stop_gateway(gateway)
+        raise
+    return procs, gateway
+
+
+def _stop_gateway(gateway):
+    if gateway is not None:
+        gateway.shutdown()
+        gateway.server_close()
 
 
 def _run_worker(experiment, parser, args):
@@ -134,9 +167,9 @@ def main(args):
 
     experiment, parser = build_from_args(args)
     experiment.instantiate()
-    workers = []
+    workers, gateway = [], None
     if getattr(args, "n_workers", 1) > 1 and not os.environ.get(_SPAWNED_ENV):
-        workers = _spawn_workers(args, experiment)
+        workers, gateway = _spawn_workers(args, experiment)
     try:
         try:
             _run_worker(experiment, parser, args)
@@ -146,6 +179,10 @@ def main(args):
             for proc in workers:
                 proc.wait()
             return 1
+        # Stats must reflect the WHOLE cohort's work, so join EVERY child
+        # first (a list, not a short-circuiting any(): stragglers would
+        # outlive the command and keep consuming budget).
+        codes = [proc.wait() for proc in workers]
     except BaseException:
         # Any other parent failure (storage errors, Ctrl-C): the cohort
         # must not be orphaned to keep consuming the budget in the
@@ -155,10 +192,10 @@ def main(args):
         for proc in workers:
             proc.wait()
         raise
-    # Stats must reflect the WHOLE cohort's work, so join EVERY child first
-    # (a list, not a short-circuiting any(): stragglers would outlive the
-    # command and keep consuming budget).
-    codes = [proc.wait() for proc in workers]
+    finally:
+        # The children's gateway outlives them: stop it only once they
+        # are all joined.
+        _stop_gateway(gateway)
     if not os.environ.get(_SPAWNED_ENV):
         # Only the parent reports; N interleaved copies of the same stats
         # block from the children would drown the terminal.

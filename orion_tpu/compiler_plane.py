@@ -137,9 +137,11 @@ def analysis_from_compiled(compiled):
     None-degrading (interop backends return None or partial dicts).
 
     Returns ``{"flops", "bytes_accessed", "argument_bytes", "output_bytes",
-    "temp_bytes", "generated_code_bytes", "hbm_bytes"}`` — ``hbm_bytes``
-    is the per-plan HBM footprint: arguments + outputs + temporaries +
-    generated code, i.e. what the executable pins while running."""
+    "temp_bytes", "generated_code_bytes", "hbm_bytes", "tpu_custom_calls"}``
+    — ``hbm_bytes`` is the per-plan HBM footprint: arguments + outputs +
+    temporaries + generated code, i.e. what the executable pins while
+    running; ``tpu_custom_calls`` counts the Pallas (Mosaic) kernels in the
+    compiled text — 0 means the fused gram did not dispatch."""
     out = {
         "flops": None,
         "bytes_accessed": None,
@@ -148,7 +150,14 @@ def analysis_from_compiled(compiled):
         "temp_bytes": None,
         "generated_code_bytes": None,
         "hbm_bytes": None,
+        "tpu_custom_calls": None,
     }
+    try:
+        out["tpu_custom_calls"] = compiled.as_text().count(
+            'custom_call_target="tpu_custom_call"'
+        )
+    except Exception:  # pragma: no cover - backend quirk, degrade
+        pass
     try:
         cost = compiled.cost_analysis()
         if isinstance(cost, (list, tuple)):  # one entry per device

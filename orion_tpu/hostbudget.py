@@ -14,8 +14,8 @@ read the SAME knob so they can never drift apart:
   flags workers over the same derived bar.
 
 ``ORION_TPU_HOST_BUDGET_FACTOR`` overrides everywhere at once, so an
-unusual runner (e.g. a remote-tunnel TPU with pathological transfer
-latency) re-tunes the whole stack without editing any gate.
+unusual runner (e.g. a host with pathological transfer latency) re-tunes
+the whole stack without editing any gate.
 """
 
 import logging

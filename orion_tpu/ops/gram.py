@@ -14,6 +14,7 @@ MLL fit differentiates through its (n, n) kernel, and a pallas_call has no
 autodiff rule — that path stays on XLA by design.
 """
 
+import concurrent.futures
 import functools
 import os
 
@@ -70,7 +71,33 @@ def fused_gram(xa, xb, inv_lengthscales, amplitude, *, kind="matern52", interpre
 
     Matches `orion_tpu.algo.gp.kernels.kernel_matrix` numerically (forward
     values; this path defines no gradient).
+
+    XLA's partitioner cannot split a Mosaic kernel.  Under an ambient
+    multi-device mesh (`jax.sharding.use_abstract_mesh`, which the fused
+    suggest step enters for its candidate scoring) the kernel therefore
+    runs per device on that device's rows of ``xa`` against all of ``xb``
+    (``shard_map``), rows zero-padded to a multiple of the device count.
+    Each output row depends on its own input row only, so the split
+    changes no value.
     """
+    mesh = jax.sharding.get_abstract_mesh()
+    gram = functools.partial(_gram, kind=kind, interpret=interpret)
+    if mesh.empty or mesh.size == 1 or mesh.manual_axes:
+        return gram(xa, xb, inv_lengthscales, amplitude)
+    m = xa.shape[0]
+    xa = _pad2(xa, -(-m // mesh.size) * mesh.size, xa.shape[1])
+    rows = jax.sharding.PartitionSpec(mesh.axis_names, None)
+    whole = jax.sharding.PartitionSpec()
+    out = jax.shard_map(
+        gram,
+        in_specs=(rows, whole, whole, whole),
+        out_specs=rows,
+        check_vma=False,
+    )(xa, xb, inv_lengthscales, amplitude)
+    return out[:m]
+
+
+def _gram(xa, xb, inv_lengthscales, amplitude, *, kind, interpret):
     from jax.experimental import pallas as pl
 
     m, d = xa.shape
@@ -110,17 +137,30 @@ def _env_opt_in():
 
 @functools.lru_cache(maxsize=1)
 def _probe():
-    """Does the fused gram actually compile AND run on the default backend?
-    (Mosaic support varies across TPU runtimes; CPU/GPU interpret mode is
-    for tests, not production dispatch.)"""
-    if jax.default_backend() not in ("tpu",):
+    """Does the fused gram run on the default backend?  Only a TPU
+    dispatches it (CPU/GPU interpret mode is for tests, not production).
+    On a TPU a kernel that fails to lower or run RAISES here: quietly
+    giving way to XLA would hide a broken kernel.  ORION_TPU_PALLAS=0 is
+    the one explicit opt-out.
+
+    The first caller is usually the trace of the fused step
+    (`cross_kernel_matrix` asks at trace time), where a jit call would be
+    staged into the outer program and could not be read back.  Trace state
+    is per thread, so the probe kernel runs on a fresh one."""
+    if jax.default_backend() != "tpu":
         return False
-    try:
+
+    def run():
         x = jnp.asarray(np.random.default_rng(0).uniform(size=(8, 4)), jnp.float32)
-        out = fused_gram(x, x, jnp.ones((4,)), jnp.asarray(1.0), kind="matern52")
-        return bool(np.isfinite(np.asarray(out)).all())
-    except Exception:  # pragma: no cover - backend-specific lowering failures
-        return False
+        return np.asarray(
+            fused_gram(x, x, jnp.ones((4,)), jnp.asarray(1.0), kind="matern52")
+        )
+
+    with concurrent.futures.ThreadPoolExecutor(max_workers=1) as pool:
+        out = pool.submit(run).result()
+    if not np.isfinite(out).all():
+        raise RuntimeError(f"fused_gram probe returned non-finite values: {out}")
+    return True
 
 
 @functools.lru_cache(maxsize=1)
@@ -139,8 +179,8 @@ def pallas_enabled():
     the compile/run probe passes: the dispatch-amortized micro-bench
     (`--op gram`, docs/performance.md) measures the fused kernel 1.1-1.4x
     over XLA on every production shape.  ORION_TPU_PALLAS=0 opts out;
-    ORION_TPU_PALLAS=1 cannot force dispatch past a FAILING probe — the
-    env var must never push Mosaic lowering errors into the suggest path."""
+    ORION_TPU_PALLAS=1 cannot force dispatch off a TPU (the probe answers
+    False there) and a kernel failure on a TPU raises from the probe."""
     if _env_opt_in() is False:
         return False
     return _probe()

@@ -75,9 +75,7 @@ def init_distributed(coordinator=None, num_processes=None, process_id=None,
     Arguments default to jax's env-based autodetection (JAX_COORDINATOR_*,
     cloud TPU metadata); pass them explicitly elsewhere.  Idempotent.
     """
-    global _distributed_initialized
-    is_init = getattr(jax.distributed, "is_initialized", None)
-    if is_init() if callable(is_init) else _distributed_initialized:
+    if jax.distributed.is_initialized():
         return
     kwargs = {}
     if coordinator is not None:
@@ -88,14 +86,4 @@ def init_distributed(coordinator=None, num_processes=None, process_id=None,
         kwargs["process_id"] = process_id
     if local_device_ids is not None:
         kwargs["local_device_ids"] = local_device_ids
-    try:
-        jax.distributed.initialize(**kwargs)
-    except RuntimeError as exc:
-        # jax builds without is_initialized(): re-init of a live cohort must
-        # keep the documented idempotency instead of crashing.
-        if "already initialized" not in str(exc).lower():
-            raise
-    _distributed_initialized = True
-
-
-_distributed_initialized = False
+    jax.distributed.initialize(**kwargs)

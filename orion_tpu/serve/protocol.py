@@ -1,4 +1,4 @@
-"""Gateway wire protocol: framing, ops, and error taxonomy.
+"""Gateway wire protocol: framing, ops, and error classes.
 
 The gateway speaks the same newline-delimited JSON framing as the netdb
 wire (one request line, one response line, 64MB line cap, torn lines

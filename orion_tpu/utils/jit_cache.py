@@ -3,12 +3,17 @@
 The fused suggest step compiles once per (padded-buffer-size, q-bucket,
 config) signature; a TPU compile costs tens of seconds, and every fresh
 process pays it again for each bucket its history growth crosses.  Pointing
-jax at an on-disk cache makes every later process (and every later bucket
-crossing in CI/benchmarks) warm.  SURVEY.md §5 assigns profiling/latency
-concerns to the TPU build; this is the biggest single lever.
+jax at an on-disk cache makes every later process warm.
 
-Opt out with ORION_TPU_JIT_CACHE=off, or point it at a custom directory.
-A user-configured jax cache dir always wins.
+Where the cache lives:
+
+- ``JAX_COMPILATION_CACHE_DIR`` set: that directory, which jax itself reads
+  at import; no code here sets another.
+- unset: ``<checkout>/.jax_cache`` (git-ignored).  The path is part of the
+  cache key, so it is fixed — a directory under ``$HOME`` would also be
+  thrown away by machines that keep only the checkout.
+
+``ORION_TPU_JIT_CACHE=off`` turns the cache off.
 """
 
 import logging
@@ -18,36 +23,32 @@ log = logging.getLogger(__name__)
 
 _DISABLE = ("0", "off", "false", "no")
 
+#: The checkout this package lives in (``orion_tpu/utils/`` -> root).
+CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_cache",
+)
+
 
 def enable_persistent_compilation_cache():
     """Idempotent; returns the cache dir in effect (None when disabled)."""
     import jax
 
-    if not hasattr(jax.config, "jax_compilation_cache_dir"):
-        return None  # jax build without a persistent cache: nothing to do
     configured = jax.config.jax_compilation_cache_dir
-    if configured:  # the user (or a test harness) already chose one
+    if configured:  # JAX_COMPILATION_CACHE_DIR, or an earlier call
         return configured
-    override = os.environ.get("ORION_TPU_JIT_CACHE", "").strip()
-    if override.lower() in _DISABLE:
+    if os.environ.get("ORION_TPU_JIT_CACHE", "").strip().lower() in _DISABLE:
         return None
-    if override and override.lower() not in ("1", "on", "true", "yes"):
-        # A path; bare enable values keep the default location (same
-        # boolean-flag convention as ORION_TPU_PALLAS).
-        cache_dir = override
-    else:
-        xdg = os.environ.get("XDG_CACHE_HOME") or os.path.expanduser("~/.cache")
-        cache_dir = os.path.join(xdg, "orion_tpu", "jax_cache")
     try:
-        os.makedirs(cache_dir, exist_ok=True)
-        # Catch the acquisition sub-jits too; below ~0.5s the write
-        # amplification outweighs the win.  Respect a user-tuned threshold
-        # (only replace jax's default), and set the dir LAST so the return
-        # value always matches the enabled/disabled state.
-        if getattr(jax.config, "jax_persistent_cache_min_compile_time_secs", None) == 1.0:
-            jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-    except Exception as exc:  # unwritable home, read-only fs, old jax…
+        os.makedirs(CHECKOUT_CACHE_DIR, exist_ok=True)
+    except OSError as exc:  # read-only checkout
         log.debug("persistent compilation cache unavailable: %s", exc)
         return None
-    return cache_dir
+    # Catch the acquisition sub-jits too; below ~0.5s the write
+    # amplification outweighs the win.  Respect a user-tuned threshold
+    # (only replace jax's default), and set the dir LAST so the return
+    # value always matches the enabled/disabled state.
+    if jax.config.jax_persistent_cache_min_compile_time_secs == 1.0:
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+    jax.config.update("jax_compilation_cache_dir", CHECKOUT_CACHE_DIR)
+    return CHECKOUT_CACHE_DIR

@@ -213,6 +213,66 @@ def test_hunt_n_workers_shares_the_budget(tmp_path, capsys):
     assert 8 <= completed <= 9
 
 
+def test_hunt_n_workers_children_never_load_the_device(tmp_path, monkeypatch):
+    """One process owns the chip: with tpu_bo, the spawned worker runs with
+    JAX_PLATFORMS=cpu and suggests through the parent's in-process gateway
+    (its algorithm is a gateway tenant), never through a device of its own."""
+    import subprocess
+
+    import yaml
+
+    from orion_tpu.serve.gateway import GatewayServer
+
+    child_envs, gateway_stats = [], []
+    real_popen, real_shutdown = subprocess.Popen, GatewayServer.shutdown
+
+    def recording_popen(cmd, *a, env=None, **kw):
+        child_envs.append(dict(env or {}))
+        return real_popen(cmd, *a, env=env, **kw)
+
+    def recording_shutdown(self):
+        gateway_stats.append(self.stats_snapshot())
+        real_shutdown(self)
+
+    monkeypatch.setattr(subprocess, "Popen", recording_popen)
+    monkeypatch.setattr(GatewayServer, "shutdown", recording_shutdown)
+    monkeypatch.delenv("ORION_SERVE_ADDRESS", raising=False)
+    config = tmp_path / "conf.yaml"
+    config.write_text(yaml.safe_dump({"algorithms": {"tpu_bo": {
+        "n_init": 2, "n_candidates": 64, "fit_steps": 2}}}))
+    # Each worker's first trial waits (at most 120 s) until the other
+    # worker runs one too: the parent cannot finish the budget before the
+    # slower-starting child suggests, and with one suggestion per producer
+    # round the child finds no pending trial to take without suggesting.
+    marks = tmp_path / "marks"
+    marks.mkdir()
+    rendezvous = tmp_path / "rendezvous.py"
+    rendezvous.write_text(
+        "import os, runpy, time\n"
+        f"marks = {str(marks)!r}\n"
+        "who = 'child' if os.environ.get('ORION_TPU_SPAWNED_WORKER') else 'parent'\n"
+        "open(os.path.join(marks, who), 'w').close()\n"
+        "deadline = time.time() + 120\n"
+        "while len(os.listdir(marks)) < 2 and time.time() < deadline:\n"
+        "    time.sleep(0.05)\n"
+        f"runpy.run_path({BLACK_BOX!r}, run_name='__main__')\n"
+    )
+    rc = cli_main(["hunt", "-n", "nwt", "-c", str(config),
+                   "--storage-path", str(tmp_path / "db.pkl"),
+                   "--max-trials", "6", "--n-workers", "2", "--pool-size", "1",
+                   "--working-dir", str(tmp_path / "w"),
+                   str(rendezvous), "-x~uniform(-5, 5)"])
+    assert rc == 0
+    workers = [e for e in child_envs if e.get("ORION_TPU_SPAWNED_WORKER")]
+    assert len(workers) == 1
+    assert workers[0]["JAX_PLATFORMS"] == "cpu"
+    assert workers[0]["ORION_SERVE_ADDRESS"].startswith("127.0.0.1:")
+    assert workers[0]["ORION_SERVE_SECRET"]
+    assert sorted(os.listdir(marks)) == ["child", "parent"]
+    [stats] = gateway_stats
+    assert stats["suggests"] >= 1  # the child's algorithm was a tenant
+
+
 def test_hunt_n_workers_refuses_memory_storage(capsys):
     rc = cli_main(["hunt", "-n", "nwm", "--debug", "--max-trials", "2",
                    "--n-workers", "2", BLACK_BOX, "-x~uniform(-5, 5)"])

@@ -121,7 +121,7 @@ def test_analysis_from_compiled_degrades_every_field_to_none():
     out = analysis_from_compiled(_FakeCompiled(raise_cost=True))
     assert set(out) == {
         "flops", "bytes_accessed", "argument_bytes", "output_bytes",
-        "temp_bytes", "generated_code_bytes", "hbm_bytes",
+        "temp_bytes", "generated_code_bytes", "hbm_bytes", "tpu_custom_calls",
     }
     assert all(v is None for v in out.values())
 
@@ -148,6 +148,7 @@ def test_lowered_analysis_fn_on_a_real_tiny_jit():
     probe = lowered_analysis_fn(toy, (jnp.ones((8,), jnp.float32),), {"k": 3})
     out = probe()
     assert set(out) >= {"flops", "hbm_bytes"}
+    assert out.pop("tpu_custom_calls") == 0  # no Pallas kernel in a toy
     # CPU exposes a cost model; whatever it reports must be float or None.
     assert all(v is None or isinstance(v, float) for v in out.values())
 

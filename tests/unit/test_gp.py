@@ -182,3 +182,20 @@ def test_tpu_bo_state_roundtrip_and_deepcopy():
     a = algo.suggest(2)
     b = fresh.suggest(2)
     assert [p["x"] for p in a] == [p["x"] for p in b]
+
+
+def test_rff_thompson_scores_candidates_at_full_precision():
+    """Both candidate-scoring matmuls ask for HIGHEST precision: at the
+    TPU's default one the sharded and one-device programs scored the same
+    candidates up to 6e-3 apart and picked different rows (PERF.md)."""
+    rng = np.random.default_rng(0)
+    x = jnp.asarray(rng.uniform(size=(32, 3)), jnp.float32)
+    state = fit_gp(x, jnp.sin(x).sum(1), jnp.ones(32), kind="matern52", n_steps=2,
+                   init=init_hypers(3))
+    cands = jnp.asarray(rng.uniform(size=(1000, 3)), jnp.float32)
+    text = jax.jit(rff_thompson, static_argnames=("q",)).lower(
+        jax.random.PRNGKey(0), state, cands, q=7).as_text()
+    scoring = [line for line in text.splitlines()
+               if "dot_general" in line and "-> tensor<1000x" in line]
+    assert len(scoring) == 2, scoring
+    assert all("precision = [HIGHEST, HIGHEST]" in line for line in scoring), scoring

@@ -82,13 +82,19 @@ def _stream_hash(exp, sort_params=False):
 #: Recorded against the pre-ring producer (seed 3, 3 produce(4) rounds over
 #: memory storage, trials left in flight): per-round = one count-gated sync
 #: read, ONE batched 4-slot register, one telemetry flush pair.
+#:
+#: The two stream hashes were re-pinned (PR 21) for the installed JAX 0.9,
+#: whose ``jax_threefry_partitionable`` default is True: the same keys draw
+#: different random bits than the JAX that recorded them.  With that flag
+#: set False, the old hashes (4c3ffe1e..., 3389f82c...) reproduce exactly,
+#: so the producer itself did not change.
 GOLDEN_ROUND_OPS = [
     "apply_batch:read/trials,count/trials",
     "apply_batch:write/trials,write/trials,write/trials,write/trials",
     "write/telemetry",
     "count/telemetry",
 ]
-GOLDEN_STREAM = "4c3ffe1e3992b49d5aaa369b315585ae"
+GOLDEN_STREAM = "0db948ff5df22747690ee2f91feb43c9"
 
 #: Recorded against the pre-ring producer: round 1 completed (so the
 #: MaxParallelStrategy has a lie value), round 2 left in flight, round 3's
@@ -104,7 +110,7 @@ GOLDEN_LIE_ROUND_OPS = [
     "write/telemetry",
     "count/telemetry",
 ]
-GOLDEN_LIE_STREAM = "3389f82c62b16822034909d90d640814"
+GOLDEN_LIE_STREAM = "8ec35f3fc6f7a0298a688a2d6ff4adba"
 
 
 def test_depth_1_storage_op_sequence_matches_pre_ring_golden():

@@ -1,14 +1,16 @@
 """Sharded-suggest parity pins (ISSUE 16): a mesh-built algorithm must
-reproduce the unsharded one BIT FOR BIT.
+reproduce the unsharded one.
 
 Three layers of the contract:
 
-- 1-device mesh == no mesh for all four GP/KDE-backed algorithms — the
-  cheapest differential: every with_sharding_constraint inserted by the
-  mesh path must be a no-op when the mesh holds one device;
-- 8-device mesh == no mesh for the fused tpu_bo round (the full SPMD
-  build: split GP fit, replicated polish splice, sharded EI/dedup pool) —
-  the same contract the promoted multichip gate asserts at q=1024;
+- 1-device mesh == no mesh, bit for bit, for all four GP/KDE-backed
+  algorithms — the cheapest differential: every with_sharding_constraint
+  inserted by the mesh path must be a no-op when the mesh holds one device;
+- 8-device mesh vs no mesh for the fused tpu_bo round (the full SPMD
+  build: split GP fit, replicated polish splice, sharded EI/dedup pool):
+  the same rows selected, equal to within ``sharding.ROWS_ATOL`` — XLA
+  promises no reduction order across shards, so the last bits may move.
+  The promoted multichip gate asserts the same at q=1024;
 - the sharding helpers themselves: mesh/spec caching (JIT004's reason to
   exist — hot paths must reuse ONE mesh object) and per-device placement
   accounting.
@@ -16,20 +18,13 @@ Three layers of the contract:
 These run under the suite's 8-device virtual CPU mesh (tests/conftest.py).
 """
 
-import os
-
 import jax
 import numpy as np
 import pytest
 
 from orion_tpu.algo.base import create_algo
+from orion_tpu.algo.sharding import STATE_RTOL, compare_rows, compare_states
 from orion_tpu.space.dsl import build_space
-
-_needs_cpu_mesh = pytest.mark.skipif(
-    os.environ.get("ORION_TPU_TEST_PLATFORM", "cpu") != "cpu",
-    reason="requires the 8-device virtual CPU mesh",
-)
-
 
 def _uniform_space(d=4):
     return build_space({f"x{i}": "uniform(0, 1)" for i in range(d)})
@@ -72,7 +67,6 @@ FOUR_ALGOS = [
 ]
 
 
-@_needs_cpu_mesh
 @pytest.mark.parametrize(
     "name,cfg,fidelity", FOUR_ALGOS, ids=[a[0] for a in FOUR_ALGOS]
 )
@@ -95,22 +89,23 @@ def test_one_device_mesh_bit_identical(name, cfg, fidelity):
         assert equal, f"{name} health field {k!r} drifts under the 1-device mesh"
 
 
-@_needs_cpu_mesh
-def test_eight_device_mesh_bit_identical_rows_state_health():
+def test_eight_device_mesh_matches_rows_state_health():
     space = _uniform_space()
     mesh_algo, plain_algo = _observed_pair("tpu_bo", space, GP_CFG, n_devices=8)
     rows_m = np.asarray(mesh_algo._suggest_cube(8))
     rows_p = np.asarray(plain_algo._suggest_cube(8))
-    np.testing.assert_array_equal(rows_m, rows_p)
+    parity = compare_rows(rows_m, rows_p)
+    assert parity["ok"], parity
     # GP state: the mesh build fits on a single device at plan time (split
-    # fit) — its posterior must still be bit-identical to the in-plan fit.
+    # fit) — its posterior must match the in-plan fit to f32 noise.
     state_m, state_p = mesh_algo._gp_state, plain_algo._gp_state
-    np.testing.assert_array_equal(np.asarray(state_m.alpha), np.asarray(state_p.alpha))
-    np.testing.assert_array_equal(
+    state = compare_states(state_m, state_p)
+    assert state["ok"], state
+    np.testing.assert_allclose(
         np.asarray(state_m.hypers.log_lengthscales),
         np.asarray(state_p.hypers.log_lengthscales),
+        rtol=STATE_RTOL,
     )
-    np.testing.assert_array_equal(np.asarray(state_m.health), np.asarray(state_p.health))
     health = mesh_algo.health_record()
     assert health["mesh_devices"] == 8
     # Fresh sharded dispatch just ran: utilization fields must be present
@@ -118,7 +113,42 @@ def test_eight_device_mesh_bit_identical_rows_state_health():
     assert 0.0 <= health["mesh_util_min_frac"] <= health["mesh_util_max_frac"] <= 1.0
 
 
-@_needs_cpu_mesh
+def test_split_fit_stays_on_one_device_in_warm_rounds(monkeypatch):
+    """Mesh mode fits the GP hypers in a separate single-device jit.  The
+    warm state a sharded step returns is replicated over the mesh; the fit
+    must still see it on one device, or it compiles again as a
+    multi-device program (18.9 s on the first warm round on a v5e 2x2)."""
+    from orion_tpu.algo import tpu_bo
+
+    seen = []
+    real = tpu_bo._fit_gp_host
+
+    def spy(x, y, mask, warm, **kw):
+        seen.append({
+            leaf.sharding.num_devices
+            for leaf in jax.tree.leaves((x, warm))
+            if isinstance(leaf, jax.Array)
+        })
+        return real(x, y, mask, warm, **kw)
+
+    monkeypatch.setattr(tpu_bo, "_fit_gp_host", spy)
+    space = _uniform_space()
+    algo = create_algo(
+        space, {"tpu_bo": dict(GP_CFG, use_mesh=True, n_devices=8)}, seed=3
+    )
+    rng = np.random.default_rng(3)
+    params = space.sample(0, n=20)
+    algo.observe(params, [{"objective": float(v)} for v in rng.normal(size=20)])
+    for _ in range(2):
+        rows = np.asarray(algo._suggest_cube(8))
+        algo.observe(
+            [{f"x{i}": float(r[i]) for i in range(4)} for r in rows],
+            [{"objective": float(v)} for v in rng.normal(size=len(rows))],
+        )
+    assert len(seen) == 2  # cold round, then the warm one
+    assert seen == [{1}, {1}]
+
+
 def test_mesh_and_spec_caches_return_same_objects():
     from orion_tpu.algo.sharding import (
         candidate_spec,
@@ -134,7 +164,6 @@ def test_mesh_and_spec_caches_return_same_objects():
     assert get_mesh(1) is not mesh_a
 
 
-@_needs_cpu_mesh
 def test_placement_fractions_cover_every_device():
     from orion_tpu.algo.sharding import (
         get_mesh,
@@ -150,7 +179,6 @@ def test_placement_fractions_cover_every_device():
     assert abs(sum(fractions.values()) - 1.0) < 1e-6
 
 
-@_needs_cpu_mesh
 def test_coalesced_mesh_dispatch_matches_standalone():
     """Gateway coalescing over mesh-built plans (tenant-parallel shard_map
     when the stack is wide enough) must reproduce standalone dispatch."""
@@ -179,4 +207,20 @@ def test_coalesced_mesh_dispatch_matches_standalone():
     assert LAST_STACK_PLACEMENT.get("tenant_parallel") is True
     for lane in range(8):
         rows, _state = got[lane]
-        np.testing.assert_array_equal(np.asarray(rows), want[lane])
+        parity = compare_rows(rows, want[lane])
+        assert parity["ok"], (lane, parity)
+
+
+def test_compare_states_flags_a_drifted_fit():
+    """Rows alone can agree while the fit drifts: the state comparison
+    holds alpha and the health vector to STATE_RTOL."""
+    from types import SimpleNamespace
+
+    alpha = np.linspace(-3.0, 3.0, 16, dtype=np.float32)
+    health = np.linspace(0.1, 2.0, 8, dtype=np.float32)
+    base = SimpleNamespace(alpha=alpha, health=health)
+    noise = SimpleNamespace(alpha=alpha * (1 + 2e-6), health=health)
+    drift = SimpleNamespace(alpha=alpha * (1 + 1e-3), health=health)
+    assert compare_states(base, noise)["ok"]
+    out = compare_states(base, drift)
+    assert not out["ok"] and out["alpha_max_abs_diff"] > 1e-3
