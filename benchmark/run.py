@@ -1,0 +1,21 @@
+"""Run one benchmark cell on this machine's TPU and print one JSON line.
+
+    python benchmark/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
+
+Run from the root of a checkout that holds ``BENCHMARK.json``; the cell,
+its configuration, traffic mix, limits and metrics are found by name.
+"""
+
+import time
+
+T_START = time.perf_counter()
+
+import os  # noqa: E402
+import sys  # noqa: E402
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+from benchmark.harness import main  # noqa: E402
+
+if __name__ == "__main__":
+    sys.exit(main(t_start=T_START))
