@@ -366,21 +366,11 @@ def bench_breakdown(rounds=4, q=Q, algo=None, n_hist=130):
     _observe(algo, X, _hartmann6_np(X))
     algo.suggest(q)  # compile
 
-    from orion_tpu.algo.tpu_bo import (
-        dispatch_prep_stats,
-        plan_prep_stats,
-        reset_dispatch_prep_stats,
-        reset_plan_prep_stats,
-    )
     from orion_tpu.core.trial import TrialBatch
 
-    # Plan-prep cache accounting over the measured rounds only: the µs the
-    # per-signature cache saves inside the dispatch stage (statics dict +
-    # signature + cold-hypers rebuilt on a miss, reused on a hit), and the
-    # µs the per-instance prep token saves on top (skipping the prep-key
-    # probe entirely on the steady path).
-    reset_plan_prep_stats()
-    reset_dispatch_prep_stats()
+    # Plan-prep cache accounting over the measured rounds only: per round,
+    # which cache counters moved and the round's ``algo.plan`` span.
+    plan_rounds = []
 
     stages = {k: [] for k in
               ("encode", "upload", "dispatch", "wait_transfer", "health",
@@ -394,8 +384,9 @@ def bench_breakdown(rounds=4, q=Q, algo=None, n_hist=130):
         t1 = time.perf_counter()
         algo.observe(params, [{"objective": float(v)} for v in yn], cube=cube)
         t2 = time.perf_counter()
-        rows = algo._suggest_cube(q)
+        rows, plan = _counted_suggest_cube(algo, q)
         t3 = time.perf_counter()
+        plan_rounds.append(plan)
         out = np.asarray(rows)
         t4 = time.perf_counter()
         algo.health_record()
@@ -407,44 +398,59 @@ def bench_breakdown(rounds=4, q=Q, algo=None, n_hist=130):
         TrialBatch(batch).prepare("bench-breakdown", submit_time=0.0).to_docs()
         t7 = time.perf_counter()
         if bench_round == 0:
+            plan_rounds.clear()
             continue  # discarded warmup round (append-jit compiles)
         for key, dt in zip(stages, (t1 - t0, t2 - t1, t3 - t2, t4 - t3,
                                     t_health - t4, t5 - t_health,
                                     t6 - t5, t7 - t6)):
             stages[key].append(dt)
     out = {k: round(1e3 * float(np.median(v)), 3) for k, v in stages.items()}
-    # SAVINGS reports like telemetry_us_saved, not stages: the dispatch
-    # medians above already CONTAIN the cache-hit prep, so the saved µs must
-    # be excluded from every host_ms sum (test_bench_smoke pins this).
-    out["prep_us_saved"] = plan_prep_stats()["saved_us"]
-    out["dispatch_us_saved"] = dispatch_prep_stats()["saved_us"]
+    # SAVINGS reports, not stages: the dispatch medians above already
+    # CONTAIN the cache-hit prep, so the saved µs must be excluded from
+    # every host_ms sum (test_bench_smoke pins this).  prep: the µs the
+    # per-signature cache saves (statics dict + signature + cold-hypers
+    # rebuilt on a miss, reused on a hit); dispatch: the µs the
+    # per-instance prep token saves on top (skipping the prep-key probe
+    # entirely on the steady path).
+    out["prep_us_saved"] = _prep_us_saved(plan_rounds, "plan.prep")
+    out["dispatch_us_saved"] = _prep_us_saved(plan_rounds, "plan.token")
     return out
 
 
-def bench_telemetry_batching(samples_per_round=4, rounds=400):
-    """Host µs per round saved by the producer's batched span bookkeeping
-    (``Telemetry.record_spans_batch`` vs one ``record_span`` per sample) —
-    the ROADMAP-item-2 down-payment number reported as
-    ``breakdown_ms["telemetry_us_saved"]``.  Measured on a PRIVATE enabled
-    registry so the timed bench phases keep their disabled-path default."""
-    import time as _time
+PREP_COUNTERS = ("plan.prep.hits", "plan.prep.misses",
+                 "plan.token.hits", "plan.token.misses")
 
-    from orion_tpu.telemetry import Telemetry
 
-    tel = Telemetry(enabled=True, span_capacity=8192)
-    args = {"count": 16}
-    t0 = _time.perf_counter()
-    for _ in range(rounds):
-        for _s in range(samples_per_round):
-            tel.record_span("bench.tel.single", duration=1e-4, args=args)
-    per_call = _time.perf_counter() - t0
-    tel.reset()
-    entries = [("bench.tel.batch", None, 1e-4, args)] * samples_per_round
-    t0 = _time.perf_counter()
-    for _ in range(rounds):
-        tel.record_spans_batch(entries)
-    batched = _time.perf_counter() - t0
-    return round((per_call - batched) / rounds * 1e6, 2)
+def _counted_suggest_cube(algo, q):
+    """``algo._suggest_cube(q)`` with telemetry on for the call: the rows,
+    and ``(counter deltas, algo.plan seconds)`` of the round."""
+    from orion_tpu.telemetry import TELEMETRY
+
+    was_enabled = TELEMETRY.enabled
+    TELEMETRY.enable()
+    try:
+        TELEMETRY.drain_spans("bench.plan")
+        before = [TELEMETRY.counter_value(c) for c in PREP_COUNTERS]
+        rows = algo._suggest_cube(q)
+        deltas = {c: TELEMETRY.counter_value(c) - b
+                  for c, b in zip(PREP_COUNTERS, before)}
+        plan_s = sum(s["dur"] for s in TELEMETRY.drain_spans("bench.plan")
+                     if s["name"] == "algo.plan")
+    finally:
+        if not was_enabled:
+            TELEMETRY.disable()
+    return rows, (deltas, plan_s)
+
+
+def _prep_us_saved(plan_rounds, cache):
+    """µs a plan-prep cache (``plan.prep`` or ``plan.token`` counters)
+    saved over the rounds: its hits times the mean ``algo.plan`` time of a
+    miss round less that of a hit round."""
+    hit = [s for deltas, s in plan_rounds if deltas[f"{cache}.hits"]]
+    miss = [s for deltas, s in plan_rounds if deltas[f"{cache}.misses"]]
+    hit_us = 1e6 * float(np.mean(hit)) if hit else 0.0
+    miss_us = 1e6 * float(np.mean(miss)) if miss else 0.0
+    return len(hit) * max(0.0, miss_us - hit_us)
 
 
 def bench_id_hash(q=1024, reps=5):
@@ -1230,12 +1236,12 @@ def _json_payload(
     # Steady-state host tax of one round: every breakdown stage that runs
     # on host (wait_transfer is device execution + transfer; storage_ms is
     # tracked separately — the pipelined commit overlaps it with dispatch;
-    # telemetry_us_saved is a SAVINGS report, not a stage).
+    # prep_us_saved and dispatch_us_saved are SAVINGS reports, not stages).
     host_ms_per_round = round(
         sum(
             v for k, v in breakdown_ms.items()
-            if k not in ("wait_transfer", "storage_ms", "telemetry_us_saved",
-                         "prep_us_saved", "dispatch_us_saved")
+            if k not in ("wait_transfer", "storage_ms", "prep_us_saved",
+                         "dispatch_us_saved")
             and v is not None
         ),
         3,
@@ -1451,8 +1457,7 @@ def _assert_health_overhead(breakdown):
     health_ms = breakdown.get("health")
     round_ms = sum(
         v for k, v in breakdown.items()
-        if k not in ("storage_ms", "telemetry_us_saved", "prep_us_saved",
-                     "dispatch_us_saved")
+        if k not in ("storage_ms", "prep_us_saved", "dispatch_us_saved")
         and v is not None
     )
     assert health_ms is not None and round_ms > 0
@@ -1470,7 +1475,6 @@ def main(smoke=False, trace_out="bench_trace.json"):
     device_ms = bench_device_decomposition()
     storage_ms, storage_ops = bench_storage()
     breakdown["storage_ms"] = storage_ms["sqlite"]
-    breakdown["telemetry_us_saved"] = bench_telemetry_batching()
     _assert_health_overhead(breakdown)
     prewarm = bench_prewarm()
     assert prewarm["retraces_after_warm"] in (None, 0), (
@@ -2292,7 +2296,6 @@ def main_smoke(trace_out="bench_trace.json"):
     breakdown = bench_breakdown(rounds=3, q=q, algo=algo, n_hist=20)
     storage_ms, storage_ops = bench_storage(q=64, rounds=1)
     breakdown["storage_ms"] = storage_ms["sqlite"]
-    breakdown["telemetry_us_saved"] = bench_telemetry_batching(rounds=50)
     _assert_health_overhead(breakdown)
     # Trial-identity gate (host-tail endgame): the cube_hash scheme must
     # beat the per-trial repr+md5 path by >= 4x at the bench batch size,
@@ -2405,8 +2408,7 @@ def main_smoke(trace_out="bench_trace.json"):
     smoke_wall_ms = round(
         sum(
             v for k, v in breakdown.items()
-            if k not in ("storage_ms", "telemetry_us_saved",
-                         "prep_us_saved", "dispatch_us_saved")
+            if k not in ("storage_ms", "prep_us_saved", "dispatch_us_saved")
             and v is not None
         ),
         3,

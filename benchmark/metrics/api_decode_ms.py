@@ -1,0 +1,10 @@
+"""Host time per round decoding suggested rows to params: the program's
+``algo.decode`` spans, from the trace."""
+
+from benchmark.metrics._program_spans import on_clock, per_round_ms, spans
+
+
+def read(ctx):
+    if not on_clock(ctx):
+        return None
+    return per_round_ms(ctx, spans(ctx, "algo.decode"))

@@ -18,6 +18,7 @@ import numpy as np
 import jax
 
 from orion_tpu.space.space import Space
+from orion_tpu.telemetry import TELEMETRY
 from orion_tpu.utils.registry import Registry
 
 algo_registry = Registry("algo")
@@ -176,16 +177,20 @@ class BaseAlgorithm:
         self._n_observed = state["n_observed"]
 
     # --- core contract -----------------------------------------------------
-    def _materialize_batch(self, cube):
-        """Decode a device cube to a :class:`SuggestionBatch`: ONE bulk
-        device->host transfer, then host-side decode — per-dimension device
-        decode would pay a host<->device round trip per dim
-        (orion_tpu.space.dims host codec mirror)."""
-        cube = np.asarray(cube, dtype=np.float32)
-        arrays = self.space.decode_flat_np(cube)
-        params = self.space.arrays_to_params(
-            arrays, fidelity_value=self._fidelity_for_new()
-        )
+    def _materialize_batch(self, cube, num=None):
+        """Decode a device cube (its first ``num`` rows, all by default) to
+        a :class:`SuggestionBatch`: ONE bulk device->host transfer, where
+        the host waits for the step (``algo.fetch``), then host-side decode
+        (``algo.decode``) — per-dimension device decode would pay a
+        host<->device round trip per dim (orion_tpu.space.dims host codec
+        mirror)."""
+        with TELEMETRY.span("algo.fetch"):
+            cube = np.asarray(cube, dtype=np.float32)[:num]
+        with TELEMETRY.span("algo.decode"):
+            arrays = self.space.decode_flat_np(cube)
+            params = self.space.arrays_to_params(
+                arrays, fidelity_value=self._fidelity_for_new()
+            )
         return SuggestionBatch(params, cube)
 
     def suggest(self, num=1):
@@ -198,10 +203,11 @@ class BaseAlgorithm:
         (suggest_batch routes overriders back to ``self.suggest`` — going
         through it here would make that pattern infinitely recursive).
         """
-        cube = self._suggest_cube(num)
-        if cube is None:
-            return None
-        return self._materialize_batch(cube).params
+        with TELEMETRY.span("algo.suggest"):
+            cube = self._suggest_cube(num)
+            if cube is None:
+                return None
+            return self._materialize_batch(cube).params
 
     def suggest_batch(self, num=1):
         """Columnar twin of :meth:`suggest`: returns a
@@ -217,10 +223,11 @@ class BaseAlgorithm:
         if type(self).suggest is not BaseAlgorithm.suggest:
             params = self.suggest(num)
             return SuggestionBatch(params, None) if params is not None else None
-        cube = self._suggest_cube(num)
-        if cube is None:
-            return None
-        return self._materialize_batch(cube)
+        with TELEMETRY.span("algo.suggest"):
+            cube = self._suggest_cube(num)
+            if cube is None:
+                return None
+            return self._materialize_batch(cube)
 
     def _suggest_cube(self, num):
         raise NotImplementedError
@@ -247,7 +254,7 @@ class BaseAlgorithm:
         not route through the batch twin, so subclass overrides delegating
         to ``super().finalize_suggest()`` cannot recurse."""
         num, cube = handle
-        return self._materialize_batch(np.asarray(cube)[:num]).params
+        return self._materialize_batch(cube, num).params
 
     def finalize_suggest_batch(self, handle):
         """Columnar finalize: force a :meth:`dispatch_suggest` handle to a
@@ -258,7 +265,7 @@ class BaseAlgorithm:
         if type(self).finalize_suggest is not BaseAlgorithm.finalize_suggest:
             return SuggestionBatch(self.finalize_suggest(handle), None)
         num, cube = handle
-        return self._materialize_batch(np.asarray(cube)[:num])
+        return self._materialize_batch(cube, num)
 
     def _fidelity_for_new(self):
         """Fidelity assigned to fresh points (max budget unless multi-fidelity
@@ -283,34 +290,38 @@ class BaseAlgorithm:
         """
         if not params_list:
             return
-        if cube is None:
-            cube = self.space.params_to_cube(params_list)
-        else:
-            cube = np.asarray(cube, dtype=np.float32)
-            if cube.shape[0] != len(params_list):
-                raise ValueError(
-                    f"cube has {cube.shape[0]} rows for "
-                    f"{len(params_list)} params"
+        with TELEMETRY.span("algo.observe"):
+            with TELEMETRY.span("algo.encode"):
+                if cube is None:
+                    cube = self.space.params_to_cube(params_list)
+                else:
+                    cube = np.asarray(cube, dtype=np.float32)
+                    if cube.shape[0] != len(params_list):
+                        raise ValueError(
+                            f"cube has {cube.shape[0]} rows for "
+                            f"{len(params_list)} params"
+                        )
+                objectives = np.asarray(
+                    [float(r["objective"]) for r in results], dtype=np.float64
                 )
-        objectives = np.asarray(
-            [float(r["objective"]) for r in results], dtype=np.float64
-        )
-        fidelities = None
-        fid = self.space.fidelity
-        if fid is not None:
-            from orion_tpu.space.params import ParamBatch
+                fidelities = None
+                fid = self.space.fidelity
+                if fid is not None:
+                    from orion_tpu.space.params import ParamBatch
 
-            if isinstance(params_list, ParamBatch) and params_list.has_column(
-                fid.name
-            ):
-                # Columnar fast path: the fidelity column comes straight
-                # out of the batch view — no per-trial dict probes.
-                col = params_list.column(fid.name)
-            else:
-                col = [p[fid.name] for p in params_list]
-            fidelities = np.asarray(col, dtype=np.int64)
-        self.observe_arrays(cube, objectives, params_list=params_list, fidelities=fidelities)
-        self._n_observed += len(params_list)
+                    if isinstance(params_list, ParamBatch) and params_list.has_column(
+                        fid.name
+                    ):
+                        # Columnar fast path: the fidelity column comes straight
+                        # out of the batch view — no per-trial dict probes.
+                        col = params_list.column(fid.name)
+                    else:
+                        col = [p[fid.name] for p in params_list]
+                    fidelities = np.asarray(col, dtype=np.int64)
+            self.observe_arrays(
+                cube, objectives, params_list=params_list, fidelities=fidelities
+            )
+            self._n_observed += len(params_list)
 
     def observe_arrays(self, cube, objectives, params_list=None, fidelities=None):
         """Device-facing observation hook; default is stateless."""

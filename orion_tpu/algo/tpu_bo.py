@@ -353,46 +353,50 @@ class TPUBO(BaseAlgorithm):
 
     # --- observation --------------------------------------------------------
     def observe_arrays(self, cube, objectives, params_list=None, fidelities=None):
-        objectives = clamp_objectives(objectives, self._y)
-        if objectives is None:
-            return
+        with TELEMETRY.span("algo.encode"):
+            objectives = clamp_objectives(objectives, self._y)
+            if objectives is None:
+                return
+            rows32 = np.asarray(cube, dtype=np.float32)
+            y32 = np.asarray(objectives, dtype=np.float32)
         prev_n = self._host.count
         prev_best = self._host.best_y  # O(1): tracked incrementally
-        rows32 = np.asarray(cube, dtype=np.float32)
-        y32 = np.asarray(objectives, dtype=np.float32)
         # O(batch) host append + O(batch) device append: only the new rows
         # cross the boundary, and no O(n) concatenate/argmin runs on host.
-        self._host.append(rows32, y32)
-        self._hist.append(rows32, y32)
-        self._maybe_prewarm(batch=y32.shape[0])
+        with TELEMETRY.span("algo.append"):
+            self._host.append(rows32, y32)
+            self._hist.append(rows32, y32)
+        with TELEMETRY.span("algo.prewarm"):
+            self._maybe_prewarm(batch=y32.shape[0])
         # Trust-region bookkeeping counts MODEL rounds only: observations of
         # the random init phase say nothing about the local model's quality.
         if self.trust_region and prev_n >= self.n_init:
-            # Decoupled from batch size: a big observe round is split into
-            # tr_update_every-sized sub-rounds (see tr_update_batch) so the
-            # box gets the same adaptation count a small-batch run would.
-            (self._tr_length, self._tr_succ, self._tr_fail,
-             n_restarts) = tr_update_batch(
-                self._tr_length, self._tr_succ, self._tr_fail,
-                prev_best, objectives, chunk=self.tr_update_every,
-                succ_tol=self.tr_succ_tol, fail_tol=self.tr_fail_tol,
-                length_init=self.tr_length_init,
-                length_min=self.tr_length_min,
-                length_max=self.tr_length_max,
-                improve_tol=self.tr_improve_tol,
-            )
-            new_best = self._host.best_y
-            if new_best < prev_best - self.tr_improve_tol * abs(prev_best):
-                # Progress: the box belongs back on the true incumbent.
-                self._tr_center = None
-            elif n_restarts:
-                # Collapse without progress: re-centering the fresh box on
-                # the SAME stuck incumbent replays the failed search (the
-                # round-4 tail diagnosis — the worst seed's box cycled
-                # 0.4 -> 0.0125 -> restart four times around one point).
-                # Restart around the best observation that is at least an
-                # average-distance/4 away instead.
-                self._tr_center = self._fresh_restart_center()
+            with TELEMETRY.span("algo.trust_region"):
+                # Decoupled from batch size: a big observe round is split into
+                # tr_update_every-sized sub-rounds (see tr_update_batch) so the
+                # box gets the same adaptation count a small-batch run would.
+                (self._tr_length, self._tr_succ, self._tr_fail,
+                 n_restarts) = tr_update_batch(
+                    self._tr_length, self._tr_succ, self._tr_fail,
+                    prev_best, objectives, chunk=self.tr_update_every,
+                    succ_tol=self.tr_succ_tol, fail_tol=self.tr_fail_tol,
+                    length_init=self.tr_length_init,
+                    length_min=self.tr_length_min,
+                    length_max=self.tr_length_max,
+                    improve_tol=self.tr_improve_tol,
+                )
+                new_best = self._host.best_y
+                if new_best < prev_best - self.tr_improve_tol * abs(prev_best):
+                    # Progress: the box belongs back on the true incumbent.
+                    self._tr_center = None
+                elif n_restarts:
+                    # Collapse without progress: re-centering the fresh box on
+                    # the SAME stuck incumbent replays the failed search (the
+                    # round-4 tail diagnosis — the worst seed's box cycled
+                    # 0.4 -> 0.0125 -> restart four times around one point).
+                    # Restart around the best observation that is at least an
+                    # average-distance/4 away instead.
+                    self._tr_center = self._fresh_restart_center()
 
     def _fresh_restart_center(self):
         """Index of the best observation usefully FAR from the incumbent
@@ -443,47 +447,48 @@ class TPUBO(BaseAlgorithm):
         n = self._host.count
         if n < self.n_init:
             return None
-        self._last_q_bucket = _next_pow2(num, floor=8)
-        center_idx = (
-            self._tr_center
-            if self._tr_center is not None and self._tr_center < n
-            else self._host.best_idx  # O(1): tracked incrementally
-        )
-        best_x = self._host.x[center_idx]
-        step_kw = self._step_kw_cache
-        if step_kw is None:
-            # tr_length is the per-round traced input (passed explicitly
-            # below); every other `_step_kw` entry is frozen at __init__,
-            # so the dict rides the instance and is never mutated after
-            # build (shared by ref with naive clones).
-            step_kw = dict(self._step_kw())
-            step_kw.pop("tr_length", None)
-            self._step_kw_cache = step_kw
-        if self.trust_region and n > self.tr_local_m:
-            # LOCAL GP (the TuRBO design): fit only the tr_local_m nearest
-            # observations to the incumbent.  A global fit has to average
-            # lengthscales over the whole landscape, washing out exactly the
-            # local structure the trust region is trying to exploit — and a
-            # 4x smaller buffer makes the per-round Cholesky ~64x cheaper.
-            # The subset is gathered ON DEVICE from the resident buffers
-            # (masked top_k, DeviceHistory.local_view): no O(n·d) host
-            # distance scan, no host gather, no upload — only the center
-            # row crosses the boundary.
-            x_dev, y_dev, mask_dev, _ = self._hist.local_view(
-                best_x, self.tr_local_m
+        with TELEMETRY.span("algo.plan"):
+            self._last_q_bucket = _next_pow2(num, floor=8)
+            center_idx = (
+                self._tr_center
+                if self._tr_center is not None and self._tr_center < n
+                else self._host.best_idx  # O(1): tracked incrementally
             )
-        else:
-            # Full-history fast path: the fit set IS the full history,
-            # which already lives on device — no O(n) re-pad or re-upload.
-            # The copula transform (whose ranks change globally with every
-            # new observation) runs in-jit over the masked device y, so
-            # nothing history-sized crosses the boundary here either.
-            x_dev, y_dev, mask_dev, _ = self._hist.fit_view()
-        return make_fused_plan(
-            self.next_key(), x_dev, y_dev, mask_dev, best_x,
-            self._gp_state, num, tr_length=self._tr_length,
-            prep_token=self._prep_token, **step_kw,
-        )
+            best_x = self._host.x[center_idx]
+            step_kw = self._step_kw_cache
+            if step_kw is None:
+                # tr_length is the per-round traced input (passed explicitly
+                # below); every other `_step_kw` entry is frozen at __init__,
+                # so the dict rides the instance and is never mutated after
+                # build (shared by ref with naive clones).
+                step_kw = dict(self._step_kw())
+                step_kw.pop("tr_length", None)
+                self._step_kw_cache = step_kw
+            if self.trust_region and n > self.tr_local_m:
+                # LOCAL GP (the TuRBO design): fit only the tr_local_m nearest
+                # observations to the incumbent.  A global fit has to average
+                # lengthscales over the whole landscape, washing out exactly the
+                # local structure the trust region is trying to exploit — and a
+                # 4x smaller buffer makes the per-round Cholesky ~64x cheaper.
+                # The subset is gathered ON DEVICE from the resident buffers
+                # (masked top_k, DeviceHistory.local_view): no O(n·d) host
+                # distance scan, no host gather, no upload — only the center
+                # row crosses the boundary.
+                x_dev, y_dev, mask_dev, _ = self._hist.local_view(
+                    best_x, self.tr_local_m
+                )
+            else:
+                # Full-history fast path: the fit set IS the full history,
+                # which already lives on device — no O(n) re-pad or re-upload.
+                # The copula transform (whose ranks change globally with every
+                # new observation) runs in-jit over the masked device y, so
+                # nothing history-sized crosses the boundary here either.
+                x_dev, y_dev, mask_dev, _ = self._hist.fit_view()
+            return make_fused_plan(
+                self.next_key(), x_dev, y_dev, mask_dev, best_x,
+                self._gp_state, num, tr_length=self._tr_length,
+                prep_token=self._prep_token, **step_kw,
+            )
 
     def consume_fused_step(self, state):
         """Accept the GPState a fused-plan dispatch produced (warm-start
@@ -1051,29 +1056,6 @@ class _PlanPrep(NamedTuple):
 
 
 _PLAN_PREP_CACHE = {}
-_PLAN_PREP_STATS = {"hits": 0, "misses": 0, "hit_ns": 0, "miss_ns": 0}
-
-
-def plan_prep_stats():
-    """Aggregate prep-cache effect for the bench breakdown: measured mean
-    prep cost on a miss vs a hit, and the µs the cache saved overall."""
-    hits = _PLAN_PREP_STATS["hits"]
-    misses = _PLAN_PREP_STATS["misses"]
-    hit_us = _PLAN_PREP_STATS["hit_ns"] / 1e3 / hits if hits else 0.0
-    miss_us = _PLAN_PREP_STATS["miss_ns"] / 1e3 / misses if misses else 0.0
-    return {
-        "hits": hits,
-        "misses": misses,
-        "hit_us_mean": hit_us,
-        "miss_us_mean": miss_us,
-        "saved_us": hits * max(0.0, miss_us - hit_us),
-    }
-
-
-def reset_plan_prep_stats():
-    _PLAN_PREP_STATS.update(hits=0, misses=0, hit_ns=0, miss_ns=0)
-
-
 #: Distinct trust-region lengths a token's device-scalar cache may hold.
 #: The TuRBO schedule walks a short halving/doubling ladder, so a runaway
 #: set means a caller feeds free-form floats — the cache resets rather
@@ -1118,30 +1100,6 @@ class PlanPrepToken:
         # deepcopy would walk device buffers; a clone starting cold only
         # costs one full prep probe.
         return type(self)()
-
-
-_DISPATCH_PREP_STATS = {"hits": 0, "misses": 0, "hit_ns": 0, "miss_ns": 0}
-
-
-def dispatch_prep_stats():
-    """Steady-path dispatch-prep effect for the bench breakdown
-    (``dispatch_us_saved``): measured mean prep cost on the token fast
-    path vs the full prep-key probe, and the µs the token saved overall."""
-    hits = _DISPATCH_PREP_STATS["hits"]
-    misses = _DISPATCH_PREP_STATS["misses"]
-    hit_us = _DISPATCH_PREP_STATS["hit_ns"] / 1e3 / hits if hits else 0.0
-    miss_us = _DISPATCH_PREP_STATS["miss_ns"] / 1e3 / misses if misses else 0.0
-    return {
-        "hits": hits,
-        "misses": misses,
-        "hit_us_mean": hit_us,
-        "miss_us_mean": miss_us,
-        "saved_us": hits * max(0.0, miss_us - hit_us),
-    }
-
-
-def reset_dispatch_prep_stats():
-    _DISPATCH_PREP_STATS.update(hits=0, misses=0, hit_ns=0, miss_ns=0)
 
 
 def _finish_plan(
@@ -1240,11 +1198,15 @@ def make_fused_plan(
     instance) layers the steady-path shortcut on top: when the token's
     pinned fast key still matches, the 16-element ``prep_key`` build and
     cache probe are skipped entirely and the round goes straight to the
-    shared plan tail (:func:`_finish_plan`) — the ``dispatch_us_saved``
-    line of the bench breakdown.  The fast key deliberately omits the
-    instance-frozen statics; see :class:`PlanPrepToken` for the contract.
+    shared plan tail (:func:`_finish_plan`).  The fast key deliberately
+    omits the instance-frozen statics; see :class:`PlanPrepToken` for the
+    contract.
+
+    Telemetry counts both caches: ``plan.token.hits``/``misses`` (the
+    token, per call that carries one) and ``plan.prep.hits``/``misses``
+    (the per-signature cache, per probe); the time they save shows in the
+    ``algo.plan`` span's histogram.
     """
-    t0 = time.perf_counter_ns()
     warm_is_none = warm_state is None
     fast_key = None
     if prep_token is not None:
@@ -1263,8 +1225,7 @@ def make_fused_plan(
                 warm_is_none, num, tr_length, prep_token.tr_cache,
                 kernel, y_transform,
             )
-            _DISPATCH_PREP_STATS["hits"] += 1
-            _DISPATCH_PREP_STATS["hit_ns"] += time.perf_counter_ns() - t0
+            TELEMETRY.count("plan.token.hits")
             return plan
     width = x.shape[1]
     prep_key = (
@@ -1333,11 +1294,9 @@ def make_fused_plan(
             steps,
         )
         _PLAN_PREP_CACHE[prep_key] = prep
-        _PLAN_PREP_STATS["misses"] += 1
-        _PLAN_PREP_STATS["miss_ns"] += time.perf_counter_ns() - t0
-        hit = False
+        TELEMETRY.count("plan.prep.misses")
     else:
-        hit = True
+        TELEMETRY.count("plan.prep.hits")
     if prep_token is not None:
         # One-tuple swap: a concurrent fast-path reader sees either the old
         # or the new (key, prep) pair, never a torn mix.
@@ -1348,11 +1307,7 @@ def make_fused_plan(
         kernel, y_transform,
     )
     if prep_token is not None:
-        _DISPATCH_PREP_STATS["misses"] += 1
-        _DISPATCH_PREP_STATS["miss_ns"] += time.perf_counter_ns() - t0
-    if hit:
-        _PLAN_PREP_STATS["hits"] += 1
-        _PLAN_PREP_STATS["hit_ns"] += time.perf_counter_ns() - t0
+        TELEMETRY.count("plan.token.misses")
     return plan
 
 
@@ -1420,36 +1375,53 @@ def run_fused_plan(plan, prewarmer=None):
     """Dispatch ONE prepared :class:`FusedPlan` through the fused jit,
     with the retrace-vs-cache-hit telemetry bracket.  Returns
     ``(rows[:num], state)`` exactly as the pre-plan entry did."""
+    if TELEMETRY.enabled:
+        rows, state = _dispatch_traced(plan, prewarmer)
+    else:
+        rows, state = _suggest_step(*plan.arrays, **plan.statics)
+    # Dedup ordered unique draws first, so the first `num` rows are the ones
+    # the un-padded call would have returned.  Rows come back as a DEVICE
+    # array slice: jax dispatch is asynchronous, so callers that defer the
+    # host transfer (BaseAlgorithm.suggest's np.asarray, or the producer's
+    # speculative prefetch) overlap the device round trip with host work
+    # instead of blocking here.
+    return rows[:plan.num], state
+
+
+def _dispatch_traced(plan, prewarmer):
+    """:func:`run_fused_plan`'s dispatch with telemetry on."""
     num = plan.num
     x = plan.arrays[1]
     # Telemetry: jax dispatch is asynchronous, so this span is the HOST
     # cost of the fused step — tracing + lowering + compile on a cache
     # miss, ~argument-handling microseconds on a hit.  The jit cache size
     # before/after distinguishes the two (a growth IS a retrace), which is
-    # how `orion-tpu info` counts recompiles a production hunt paid.
-    tel_t0 = cache_size = None
-    if TELEMETRY.enabled:
-        cache_size = getattr(_suggest_step, "_cache_size", None)
-        try:
-            tel_before = cache_size() if cache_size is not None else -1
-        except Exception:  # private jax API — degrade, never raise into suggest
-            cache_size, tel_before = None, -1
-        # Background prewarm compiles insert cache entries too: sample the
-        # completed-prewarm count around the dispatch so a prewarm landing
-        # mid-window is not booked as a synchronous retrace (jax.retraces
-        # must report only the stalls THIS call paid).  Scoped to the
-        # caller's own prewarmer when given — only ITS compiles share
-        # these jit signatures; the process-global fallback would let an
-        # unrelated instance's warm mask a genuine retrace here.
-        tel_completed = (
-            prewarmer.completed_count
-            if prewarmer is not None
-            else completed_prewarm_count
-        )
-        tel_prewarms_before = tel_completed()
-        tel_t0 = time.perf_counter()
-    rows, state = _suggest_step(*plan.arrays, **plan.statics)
-    if tel_t0 is not None:
+    # how `orion-tpu info` counts recompiles a production hunt paid.  The
+    # span is on the profiler's clock as ``jax.suggest_step.dispatch``; its
+    # record is renamed ``jax.suggest_step.compile`` on a retrace.
+    cache_size = getattr(_suggest_step, "_cache_size", None)
+    try:
+        tel_before = cache_size() if cache_size is not None else -1
+    except Exception:  # private jax API — degrade, never raise into suggest
+        cache_size, tel_before = None, -1
+    # Background prewarm compiles insert cache entries too: sample the
+    # completed-prewarm count around the dispatch so a prewarm landing
+    # mid-window is not booked as a synchronous retrace (jax.retraces
+    # must report only the stalls THIS call paid).  Scoped to the
+    # caller's own prewarmer when given — only ITS compiles share
+    # these jit signatures; the process-global fallback would let an
+    # unrelated instance's warm mask a genuine retrace here.
+    tel_completed = (
+        prewarmer.completed_count
+        if prewarmer is not None
+        else completed_prewarm_count
+    )
+    tel_prewarms_before = tel_completed()
+    tel_t0 = time.perf_counter()
+    with TELEMETRY.span(
+        "jax.suggest_step.dispatch", args={"q": int(num), "n": int(x.shape[0])}
+    ) as span:
+        rows, state = _suggest_step(*plan.arrays, **plan.statics)
         try:
             retraced = (
                 cache_size is not None
@@ -1468,35 +1440,26 @@ def run_fused_plan(plan, prewarmer=None):
             )
         except Exception:  # private jax API — degrade, never raise into suggest
             retraced = False
-        TELEMETRY.record_span(
-            "jax.suggest_step.compile" if retraced else "jax.suggest_step.dispatch",
-            start=tel_t0,
-            args={"q": int(num), "n": int(x.shape[0])},
-        )
         if retraced:
-            TELEMETRY.count("jax.retraces")
-            # Compiler-plane attribution (orion_tpu.compiler_plane): the
-            # registry diffs this signature against the nearest prior one
-            # in the fused_plan family, emits the flight `jax.retrace`
-            # event naming the changed statics (the timeline entry a crash
-            # post-mortem wants), and keeps a lazy cost/memory closure —
-            # shape specs only, never the arrays — for cold-path analysis
-            # (bench's compiler block, `orion-tpu profile`).
-            COMPILE_REGISTRY.record_retrace(
-                "fused_plan",
-                fields_from_plan_signature(plan.signature),
-                seconds=time.perf_counter() - tel_t0,
-                analysis_fn=lowered_analysis_fn(
-                    _suggest_step, plan.arrays, plan.statics
-                ),
-            )
-    # Dedup ordered unique draws first, so the first `num` rows are the ones
-    # the un-padded call would have returned.  Rows come back as a DEVICE
-    # array slice: jax dispatch is asynchronous, so callers that defer the
-    # host transfer (BaseAlgorithm.suggest's np.asarray, or the producer's
-    # speculative prefetch) overlap the device round trip with host work
-    # instead of blocking here.
-    return rows[:num], state
+            span.rename("jax.suggest_step.compile")
+    if retraced:
+        TELEMETRY.count("jax.retraces")
+        # Compiler-plane attribution (orion_tpu.compiler_plane): the
+        # registry diffs this signature against the nearest prior one
+        # in the fused_plan family, emits the flight `jax.retrace`
+        # event naming the changed statics (the timeline entry a crash
+        # post-mortem wants), and keeps a lazy cost/memory closure —
+        # shape specs only, never the arrays — for cold-path analysis
+        # (bench's compiler block, `orion-tpu profile`).
+        COMPILE_REGISTRY.record_retrace(
+            "fused_plan",
+            fields_from_plan_signature(plan.signature),
+            seconds=time.perf_counter() - tel_t0,
+            analysis_fn=lowered_analysis_fn(
+                _suggest_step, plan.arrays, plan.statics
+            ),
+        )
+    return rows, state
 
 
 @partial(jax.jit, static_argnames=("kernel", "fit_steps", "y_transform"))
@@ -1605,120 +1568,125 @@ def _suggest_step(
     fidelity column to max budget so selection optimizes the predicted
     FULL-budget value).  Returned rows include only the free columns.
     """
-    if mesh is not None:
-        # Pin the fit side REPLICATED before anything touches it: sharding
-        # propagation from the candidate constraint below would otherwise
-        # partition the O(n^2) GP fit too, re-ordering its reductions — the
-        # fit is tiny next to the O(m·F) candidate work, and replicating it
-        # keeps every device computing the single-device fit (the sharded
-        # gate's parity contract, sharding.compare_rows).
-        rep = replicated(mesh)
-        x, y, mask, best_x, warm_hypers = jax.tree.map(
-            lambda a: jax.lax.with_sharding_constraint(a, rep),
-            (x, y, mask, best_x, warm_hypers),
-        )
-    state = fit_gp(
-        x, y, mask, kind=kernel, n_steps=fit_steps, init=warm_hypers,
-        y_transform=y_transform,
-    )
-    if mesh is not None:
-        state = jax.tree.map(
-            lambda a: jax.lax.with_sharding_constraint(a, rep), state
-        )
-    k_cand, k_acq = jax.random.split(key)
-    d_free = x.shape[1] - fixed_tail_cols
-    if trust_region:
-        cov_chol, elite_mu = _topk_cov_chol(
-            x[:, :d_free], y, mask, d_free, k=min(64, x.shape[0])
-        )
-        lengthscales = jnp.exp(state.hypers.log_lengthscales[:d_free])
-        free_candidates = _make_tr_candidates(
-            k_cand,
-            n_candidates,
-            d_free,
-            best_x[:d_free],
-            tr_length,
-            lengthscales,
-            local_frac,
-            cov_chol,
-            elite_mu,
-            perturb_dims=tr_perturb_dims,
-        )
-        # Gradient-polish a handful of elite-covariance-jittered incumbent
-        # copies on the posterior mean and splice them over the pool's tail
-        # (keeps the pool size, and with it the candidates-divide-mesh
-        # invariant, unchanged) — acquisition still judges them against the
-        # random candidates, so exploitation sharpens without another full
-        # posterior pass over the pool.
-        k_polish = jax.random.fold_in(k_cand, 7)
-        lb, ub = _tr_box(best_x[:d_free], tr_length, lengthscales)
-        # Scale the exploiter count with the batch: at q=512 eight polished
-        # points would be a rounding error in the pool.  Clamped to half the
-        # pool — a small-n_candidates config must not have the splice eat the
-        # whole pool (changing the candidate count breaks the
-        # candidates-divide-mesh invariant and select_q's k <= pool assert).
-        n_polish = max(1, min(64, max(8, q // 16), n_candidates // 2))
-        starts = jnp.clip(
-            best_x[None, :d_free]
-            + 0.5 * jax.random.normal(k_polish, (n_polish, d_free)) @ cov_chol.T,
-            lb,
-            ub,
-        )
+    # Named scopes tag the step's ops by stage (fit, candidates, acquire,
+    # posterior, select, health) in the HLO metadata and the device trace.
+    with jax.named_scope("fit"):
         if mesh is not None:
-            # Pin the polish segment REPLICATED on both sides.  Without the
-            # pins, the candidate constraint below back-propagates into this
-            # tail-of-pool computation and XLA compiles the tiny start
-            # matmul and the 30-step descent scan per-partition — with a
-            # different float association than the single-device module
-            # (measured: the splice rows drift by ulps, which moves
-            # suggestion rows AND acq_ei_mean).  Pinned, the segment
-            # compiles once, replicated, bit-identical to unsharded.
+            # Pin the fit side REPLICATED before anything touches it: sharding
+            # propagation from the candidate constraint below would otherwise
+            # partition the O(n^2) GP fit too, re-ordering its reductions — the
+            # fit is tiny next to the O(m·F) candidate work, and replicating it
+            # keeps every device computing the single-device fit (the sharded
+            # gate's parity contract, sharding.compare_rows).
             rep = replicated(mesh)
-            starts = jax.lax.with_sharding_constraint(starts, rep)
-        polished = _polish_candidates(
-            state, kernel, starts, lb, ub, fixed_tail_cols=fixed_tail_cols
+            x, y, mask, best_x, warm_hypers = jax.tree.map(
+                lambda a: jax.lax.with_sharding_constraint(a, rep),
+                (x, y, mask, best_x, warm_hypers),
+            )
+        state = fit_gp(
+            x, y, mask, kind=kernel, n_steps=fit_steps, init=warm_hypers,
+            y_transform=y_transform,
         )
         if mesh is not None:
-            polished = jax.lax.with_sharding_constraint(polished, rep)
-        free_candidates = jnp.concatenate(
-            [free_candidates[:-n_polish], polished], axis=0
-        )
-    else:
-        free_candidates = _make_candidates(
-            k_cand, n_candidates, d_free, best_x[:d_free], local_frac, local_sigma
-        )
-    if mesh is not None:
-        # Data-parallel over the candidate axis: XLA's SPMD partitioner
-        # splits generation+scoring per shard and inserts the ICI
-        # collectives for the cross-candidate argmin/top-k reductions.
-        free_candidates = jax.lax.with_sharding_constraint(
-            free_candidates, candidate_sharding(mesh)
-        )
-    if fixed_tail_cols:
-        candidates = jnp.concatenate(
-            [
-                free_candidates,
-                jnp.ones(
-                    (free_candidates.shape[0], fixed_tail_cols),
-                    free_candidates.dtype,
-                ),
-            ],
-            axis=1,
-        )
-    else:
-        candidates = free_candidates
-    y_norm = (state.y - state.y_mean) / state.y_std
-    if fixed_tail_cols:
-        # Candidates are scored at max context (tail pinned to 1), so the EI
-        # incumbent must be the best observation AT the top context tier — a
-        # lucky low-fidelity value would otherwise be unattainable for every
-        # candidate and flatten EI to ~0.
-        s_col = x[:, -1]
-        s_max = jnp.max(jnp.where(mask > 0, s_col, -jnp.inf))
-        top = (mask > 0) & (s_col >= s_max - 1e-6)
-        best = jnp.min(jnp.where(top, y_norm, jnp.inf))
-    else:
-        best = jnp.min(jnp.where(state.mask > 0, y_norm, jnp.inf))
+            state = jax.tree.map(
+                lambda a: jax.lax.with_sharding_constraint(a, rep), state
+            )
+    with jax.named_scope("candidates"):
+        k_cand, k_acq = jax.random.split(key)
+        d_free = x.shape[1] - fixed_tail_cols
+        if trust_region:
+            cov_chol, elite_mu = _topk_cov_chol(
+                x[:, :d_free], y, mask, d_free, k=min(64, x.shape[0])
+            )
+            lengthscales = jnp.exp(state.hypers.log_lengthscales[:d_free])
+            free_candidates = _make_tr_candidates(
+                k_cand,
+                n_candidates,
+                d_free,
+                best_x[:d_free],
+                tr_length,
+                lengthscales,
+                local_frac,
+                cov_chol,
+                elite_mu,
+                perturb_dims=tr_perturb_dims,
+            )
+            # Gradient-polish a handful of elite-covariance-jittered incumbent
+            # copies on the posterior mean and splice them over the pool's tail
+            # (keeps the pool size, and with it the candidates-divide-mesh
+            # invariant, unchanged) — acquisition still judges them against the
+            # random candidates, so exploitation sharpens without another full
+            # posterior pass over the pool.
+            k_polish = jax.random.fold_in(k_cand, 7)
+            lb, ub = _tr_box(best_x[:d_free], tr_length, lengthscales)
+            # Scale the exploiter count with the batch: at q=512 eight polished
+            # points would be a rounding error in the pool.  Clamped to half the
+            # pool — a small-n_candidates config must not have the splice eat the
+            # whole pool (changing the candidate count breaks the
+            # candidates-divide-mesh invariant and select_q's k <= pool assert).
+            n_polish = max(1, min(64, max(8, q // 16), n_candidates // 2))
+            starts = jnp.clip(
+                best_x[None, :d_free]
+                + 0.5 * jax.random.normal(k_polish, (n_polish, d_free)) @ cov_chol.T,
+                lb,
+                ub,
+            )
+            if mesh is not None:
+                # Pin the polish segment REPLICATED on both sides.  Without the
+                # pins, the candidate constraint below back-propagates into this
+                # tail-of-pool computation and XLA compiles the tiny start
+                # matmul and the 30-step descent scan per-partition — with a
+                # different float association than the single-device module
+                # (measured: the splice rows drift by ulps, which moves
+                # suggestion rows AND acq_ei_mean).  Pinned, the segment
+                # compiles once, replicated, bit-identical to unsharded.
+                rep = replicated(mesh)
+                starts = jax.lax.with_sharding_constraint(starts, rep)
+            polished = _polish_candidates(
+                state, kernel, starts, lb, ub, fixed_tail_cols=fixed_tail_cols
+            )
+            if mesh is not None:
+                polished = jax.lax.with_sharding_constraint(polished, rep)
+            free_candidates = jnp.concatenate(
+                [free_candidates[:-n_polish], polished], axis=0
+            )
+        else:
+            free_candidates = _make_candidates(
+                k_cand, n_candidates, d_free, best_x[:d_free], local_frac, local_sigma
+            )
+        if mesh is not None:
+            # Data-parallel over the candidate axis: XLA's SPMD partitioner
+            # splits generation+scoring per shard and inserts the ICI
+            # collectives for the cross-candidate argmin/top-k reductions.
+            free_candidates = jax.lax.with_sharding_constraint(
+                free_candidates, candidate_sharding(mesh)
+            )
+        if fixed_tail_cols:
+            candidates = jnp.concatenate(
+                [
+                    free_candidates,
+                    jnp.ones(
+                        (free_candidates.shape[0], fixed_tail_cols),
+                        free_candidates.dtype,
+                    ),
+                ],
+                axis=1,
+            )
+        else:
+            candidates = free_candidates
+    with jax.named_scope("acquire"):
+        y_norm = (state.y - state.y_mean) / state.y_std
+        if fixed_tail_cols:
+            # Candidates are scored at max context (tail pinned to 1), so the EI
+            # incumbent must be the best observation AT the top context tier — a
+            # lucky low-fidelity value would otherwise be unattainable for every
+            # candidate and flatten EI to ~0.
+            s_col = x[:, -1]
+            s_max = jnp.max(jnp.where(mask > 0, s_col, -jnp.inf))
+            top = (mask > 0) & (s_col >= s_max - 1e-6)
+            best = jnp.min(jnp.where(top, y_norm, jnp.inf))
+        else:
+            best = jnp.min(jnp.where(state.mask > 0, y_norm, jnp.inf))
     # Candidate scoring sees the mesh as its ambient one: the fused Pallas
     # gram, which XLA cannot partition, splits its rows over it itself.
     with (
@@ -1726,62 +1694,68 @@ def _suggest_step(
         if mesh is not None
         else contextlib.nullcontext()
     ):
-        if acq == "joint_thompson":
-            idx = joint_thompson(k_acq, state, candidates, q, kind=kernel)
-        else:
-            idx = acquire(
-                k_acq, state, candidates, q, kind=kernel, acq=acq, best=best,
-                beta=beta,
-            )
-        mean, std = posterior_norm(state, candidates, kind=kernel)
-    ei = expected_improvement(mean, std, best)
-    ei_rank = select_q(ei, min(4 * q, n_candidates))
-    if trust_region:
-        # Guarantee one pure-exploitation member per batch: the pool's
-        # posterior-mean minimizer (usually a gradient-polished point).
-        # Thompson noise rarely selects it, yet it is the single highest
-        # expected payoff — CMA-style descent wants it evaluated every round.
-        # UNLESS it is already observed: once the box has converged, polish
-        # lands on the incumbent bit-for-bit every round, and injecting it
-        # again would re-suggest a stored point each batch — the producer
-        # then loops on DuplicateKeyError until SampleTimeout (small pools
-        # hit this within two rounds).
-        exploit_idx = jnp.argmin(mean)
-        exploit_cand = jnp.take(free_candidates, exploit_idx, axis=0)
-        d2_obs = jnp.sum((x[:, :d_free] - exploit_cand[None, :]) ** 2, axis=1)
-        already_observed = jnp.any((d2_obs < 1e-12) & (mask > 0))
-        injected = jnp.where(already_observed, idx[0], exploit_idx)
-        idx = jnp.concatenate([injected[None], idx])[:q]
-    final_idx = _dedup_fill_device(idx, ei_rank, q)
-    # Packed per-round health vector (health.DEVICE_HEALTH_FIELDS), built
-    # entirely from intermediates this step already computed — a handful of
-    # reductions, attached to the returned state so no signature changes
-    # and no extra device->host syncs (the vector is read lazily after the
-    # q-row transfer already materialized the round).
-    ls = jnp.exp(state.hypers.log_lengthscales[:d_free])
-    sorted_idx = jnp.sort(final_idx)
-    n_unique = 1.0 + jnp.sum((sorted_idx[1:] != sorted_idx[:-1]).astype(ls.dtype))
-    ei_stats = ei
-    if mesh is not None:
-        # Health-only copy of the EI vector, gathered replicated: a mean
-        # over the SHARDED axis is per-shard partials + all-reduce, whose
-        # float association (and so the last ulp of acq_ei_mean) would vary
-        # with the mesh size.  The gather pins the reduction to the
-        # single-device association — one all-gather of m floats on the
-        # health path, nothing on the selection path.
-        ei_stats = jax.lax.with_sharding_constraint(ei, replicated(mesh))
-    health = jnp.stack(
-        [
-            state.mll,
-            jnp.min(ls),
-            jnp.mean(ls),
-            jnp.max(ls),
-            jnp.exp(state.hypers.log_noise),
-            jnp.max(ei_stats),
-            jnp.mean(ei_stats),
-            n_unique / q,
-        ]
-    ).astype(jnp.float32)
-    state = state._replace(health=health)
-    return jnp.take(free_candidates, final_idx, axis=0), state
+        with jax.named_scope("acquire"):
+            if acq == "joint_thompson":
+                idx = joint_thompson(k_acq, state, candidates, q, kind=kernel)
+            else:
+                idx = acquire(
+                    k_acq, state, candidates, q, kind=kernel, acq=acq, best=best,
+                    beta=beta,
+                )
+        with jax.named_scope("posterior"):
+            mean, std = posterior_norm(state, candidates, kind=kernel)
+    with jax.named_scope("posterior"):
+        ei = expected_improvement(mean, std, best)
+    with jax.named_scope("select"):
+        ei_rank = select_q(ei, min(4 * q, n_candidates))
+        if trust_region:
+            # Guarantee one pure-exploitation member per batch: the pool's
+            # posterior-mean minimizer (usually a gradient-polished point).
+            # Thompson noise rarely selects it, yet it is the single highest
+            # expected payoff — CMA-style descent wants it evaluated every round.
+            # UNLESS it is already observed: once the box has converged, polish
+            # lands on the incumbent bit-for-bit every round, and injecting it
+            # again would re-suggest a stored point each batch — the producer
+            # then loops on DuplicateKeyError until SampleTimeout (small pools
+            # hit this within two rounds).
+            exploit_idx = jnp.argmin(mean)
+            exploit_cand = jnp.take(free_candidates, exploit_idx, axis=0)
+            d2_obs = jnp.sum((x[:, :d_free] - exploit_cand[None, :]) ** 2, axis=1)
+            already_observed = jnp.any((d2_obs < 1e-12) & (mask > 0))
+            injected = jnp.where(already_observed, idx[0], exploit_idx)
+            idx = jnp.concatenate([injected[None], idx])[:q]
+        final_idx = _dedup_fill_device(idx, ei_rank, q)
+        rows = jnp.take(free_candidates, final_idx, axis=0)
+    with jax.named_scope("health"):
+        # Packed per-round health vector (health.DEVICE_HEALTH_FIELDS), built
+        # entirely from intermediates this step already computed — a handful of
+        # reductions, attached to the returned state so no signature changes
+        # and no extra device->host syncs (the vector is read lazily after the
+        # q-row transfer already materialized the round).
+        ls = jnp.exp(state.hypers.log_lengthscales[:d_free])
+        sorted_idx = jnp.sort(final_idx)
+        n_unique = 1.0 + jnp.sum((sorted_idx[1:] != sorted_idx[:-1]).astype(ls.dtype))
+        ei_stats = ei
+        if mesh is not None:
+            # Health-only copy of the EI vector, gathered replicated: a mean
+            # over the SHARDED axis is per-shard partials + all-reduce, whose
+            # float association (and so the last ulp of acq_ei_mean) would vary
+            # with the mesh size.  The gather pins the reduction to the
+            # single-device association — one all-gather of m floats on the
+            # health path, nothing on the selection path.
+            ei_stats = jax.lax.with_sharding_constraint(ei, replicated(mesh))
+        health = jnp.stack(
+            [
+                state.mll,
+                jnp.min(ls),
+                jnp.mean(ls),
+                jnp.max(ls),
+                jnp.exp(state.hypers.log_noise),
+                jnp.max(ei_stats),
+                jnp.mean(ei_stats),
+                n_unique / q,
+            ]
+        ).astype(jnp.float32)
+        state = state._replace(health=health)
+    return rows, state
 

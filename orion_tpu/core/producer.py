@@ -22,6 +22,7 @@ from orion_tpu.devmem import sample_memory
 from orion_tpu.health import FLIGHT, flight_events_as_spans
 from orion_tpu.storage.retry import RetryPolicy
 from orion_tpu.telemetry import TELEMETRY, current_trace_context
+
 from orion_tpu.utils.exceptions import (
     AlgorithmExhausted,
     DuplicateKeyError,
@@ -114,10 +115,6 @@ class Producer:
         self._n_in_flight = 0  # status == reserved (someone is executing)
         self._n_reservable = 0  # new/suspended/interrupted (worker can consume)
         self._pending_timings = []
-        # Telemetry span entries buffered per round and booked in ONE
-        # record_spans_batch call at flush time — the per-sample
-        # record_span each paid a lock round-trip inside the hot loop.
-        self._pending_spans = []
         # One optimization-health record per produce round (orion_tpu
         # .health), built at round end and flushed through the storage
         # health channel next to the spans/metrics.
@@ -185,42 +182,43 @@ class Producer:
         completed history each time costs O(trials) per call) and keeps
         the single full fetch elsewhere — see its docstring for the
         consistency and ordering contract."""
-        if self._tree_fetcher is not None:
-            trials = self._tree_fetcher.fetch()
-        else:
-            # Every 16th sync forces the gate open: the count gate assumes
-            # the completed count only grows, which a concurrent
-            # db-level remove of a completed trial (offset by a fresh
-            # completion) could violate — the periodic full read bounds
-            # that staleness window instead of trusting the invariant
-            # forever.
-            self._update_epoch += 1
-            known = self._n_completed_seen if self._update_epoch % 16 else -1
-            trials, self._n_completed_seen = (
-                self.experiment.storage.fetch_update_view(self.experiment, known)
-            )
-        completed = [t for t in trials if t.status == "completed" and t.objective]
-        incomplete = [t for t in trials if not t.is_stopped]
-        # Exhaustion/backoff accounting counts THIS experiment's trials only:
-        # the EVC tree fetch includes the family's trials, which this worker
-        # can never reserve and whose completions feed ancestors, not us.
-        own_id = self.experiment.id
-        own = [t for t in trials if t.experiment == own_id]
-        self._n_in_flight = sum(t.status == "reserved" for t in own)
-        self._n_reservable = sum(t.status in RESERVABLE_STATUSES for t in own)
-        self._update_algorithm(completed)
-        # Bound the columnar cache: stopped trials are never lied about
-        # again, so their rows are dead weight.  Completed-with-objective
-        # trials were just observed (and evicted) above; this sweep covers
-        # broken / interrupted / objective-less terminals, which would
-        # otherwise leak one row per failed trial forever.  (A resumed
-        # interrupted trial simply re-encodes on its next cache miss.)
-        if self._cube_cache:
-            for t in trials:
-                if t.is_stopped:
-                    self._cube_cache.pop(t.id, None)
-        self._update_naive_algorithm(incomplete)
-        self._flush_timings()
+        with TELEMETRY.span("producer.update"):
+            if self._tree_fetcher is not None:
+                trials = self._tree_fetcher.fetch()
+            else:
+                # Every 16th sync forces the gate open: the count gate assumes
+                # the completed count only grows, which a concurrent
+                # db-level remove of a completed trial (offset by a fresh
+                # completion) could violate — the periodic full read bounds
+                # that staleness window instead of trusting the invariant
+                # forever.
+                self._update_epoch += 1
+                known = self._n_completed_seen if self._update_epoch % 16 else -1
+                trials, self._n_completed_seen = (
+                    self.experiment.storage.fetch_update_view(self.experiment, known)
+                )
+            completed = [t for t in trials if t.status == "completed" and t.objective]
+            incomplete = [t for t in trials if not t.is_stopped]
+            # Exhaustion/backoff accounting counts THIS experiment's trials only:
+            # the EVC tree fetch includes the family's trials, which this worker
+            # can never reserve and whose completions feed ancestors, not us.
+            own_id = self.experiment.id
+            own = [t for t in trials if t.experiment == own_id]
+            self._n_in_flight = sum(t.status == "reserved" for t in own)
+            self._n_reservable = sum(t.status in RESERVABLE_STATUSES for t in own)
+            self._update_algorithm(completed)
+            # Bound the columnar cache: stopped trials are never lied about
+            # again, so their rows are dead weight.  Completed-with-objective
+            # trials were just observed (and evicted) above; this sweep covers
+            # broken / interrupted / objective-less terminals, which would
+            # otherwise leak one row per failed trial forever.  (A resumed
+            # interrupted trial simply re-encodes on its next cache miss.)
+            if self._cube_cache:
+                for t in trials:
+                    if t.is_stopped:
+                        self._cube_cache.pop(t.id, None)
+            self._update_naive_algorithm(incomplete)
+            self._flush_timings()
 
     def _update_algorithm(self, completed):
         fresh = [t for t in completed if t.id not in self._observed_ids]
@@ -229,10 +227,11 @@ class Producer:
             results = [_trial_results(t) for t in fresh]
             cube = self._cube_rows_for(fresh)
             t0 = time.perf_counter()
-            if cube is not None:
-                self.algorithm.observe(params, results, cube=cube)
-            else:  # pre-columnar plugin signature
-                self.algorithm.observe(params, results)
+            with TELEMETRY.span("producer.observe", args={"count": len(fresh)}):
+                if cube is not None:
+                    self.algorithm.observe(params, results, cube=cube)
+                else:  # pre-columnar plugin signature
+                    self.algorithm.observe(params, results)
             self._record_timing("observe", time.perf_counter() - t0, len(fresh))
             self.strategy.observe(params, results)
             for t in fresh:
@@ -268,32 +267,12 @@ class Producer:
         return np.stack(rows)
 
     def _record_timing(self, op, duration, count):
-        """Buffer a timing sample; flushed once per produce()/update() round
-        so telemetry never adds a storage write inside the hot retry loop.
-
-        The same sample also feeds the process-wide telemetry registry as a
-        ``producer.{op}`` span + histogram entry — BUFFERED like the
-        storage samples and booked in one ``record_spans_batch`` call at
-        flush time, so the hot loop pays no registry lock per sample (the
-        saved host µs are what bench.py's ``telemetry_us_saved`` reports).
-        The span start is captured here (now - duration) so batching does
-        not shift the record on the trace timeline."""
+        """Buffer a timing sample for the storage timings channel (read by
+        ``orion-tpu info``); flushed once per produce()/update() round so
+        telemetry never adds a storage write inside the hot retry loop.
+        The registry's ``producer.{op}`` span of the same phase is a
+        ``with`` span around it at the call site."""
         self._pending_timings.append((op, duration, count))
-        # Guarded: the span name f-string and args dict must not be
-        # allocated per sample when telemetry is off — this runs inside
-        # every produce()/update() round.  The ambient TraceContext is
-        # captured NOW (fifth element): the batch flushes at round end,
-        # when the ambient may already belong to the next round.
-        if TELEMETRY.enabled:
-            self._pending_spans.append(
-                (
-                    f"producer.{op}",
-                    time.perf_counter() - duration,
-                    duration,
-                    {"count": count},
-                    current_trace_context(),
-                )
-            )
 
     def _flush_timings(self, force_metrics=False):
         """Telemetry must never break the run (SURVEY §5 timing hooks).
@@ -310,15 +289,12 @@ class Producer:
         samples, self._pending_timings = self._pending_timings, []
         if not samples and not TELEMETRY.enabled and not FLIGHT.enabled:
             return
-        # Book the round's buffered producer spans in one registry call
-        # BEFORE draining, so they ride this very flush to storage.
-        if self._pending_spans:
-            pending, self._pending_spans = self._pending_spans, []
-            TELEMETRY.record_spans_batch(pending)
         try:
             if samples:
                 self.experiment.storage.record_timings(self.experiment, samples)
-            spans = TELEMETRY.drain_spans() if TELEMETRY.enabled else []
+            # The storage channel's own cursor: in-process readers of the
+            # registry (the default cursor) still see every span.
+            spans = TELEMETRY.drain_spans("storage") if TELEMETRY.enabled else []
             if FLIGHT.enabled:
                 # Mirror drained flight events into the spans channel as
                 # flight.* records, so `orion-tpu flight-record -n NAME`
@@ -484,150 +460,26 @@ class Producer:
         start = time.time()
         speculative = self._take_speculative(pool_size)
         registered_trials = []
+        retry = False
         while registered < pool_size:
-            if time.time() - start > self.max_idle_time:
-                raise SampleTimeout(
-                    f"algorithm produced no new unique point in {self.max_idle_time}s"
-                )
-            t0 = time.perf_counter()
-            if speculative is not None:
-                # Already timed by _take_speculative (the residual transfer).
-                suggested, speculative = speculative, None
+            if retry:
+                # A pass after one that hit duplicates: its backoff,
+                # re-suggest and register are one ``producer.retry``.
+                with TELEMETRY.span("producer.retry"):
+                    self.backoff()
+                    outcome = self._produce_pass(
+                        pool_size, own_in_flight, registered, registered_trials,
+                        start, None,
+                    )
             else:
-                # Columnar flow: the suggestion crosses the boundary as a
-                # (q, d) array; batch.params is a LAZY ParamBatch — the
-                # storage documents build straight from its columns below,
-                # and per-point dicts only materialize at plugin-compat
-                # boundaries (register_suggestion overrides, lie strategy).
-                batch = self.naive_algorithm.suggest_batch(
-                    pool_size - registered
+                outcome = self._produce_pass(
+                    pool_size, own_in_flight, registered, registered_trials,
+                    start, speculative,
                 )
-                suggested = batch.params if batch is not None else None
-                # Advance ONLY the real algo's RNG stream, never its full
-                # state: the naive copy has observed fantasy lies, and
-                # syncing its whole state_dict would permanently inject
-                # those rows into the real algorithm (compounding every
-                # round).
-                self.algorithm.rng_key = self.naive_algorithm.rng_key
-                if suggested is not None:
-                    self._record_timing(
-                        "suggest", time.perf_counter() - t0, len(suggested)
-                    )
-            if suggested is None:
-                log.debug("algorithm opted out of suggesting")
-                # Re-sync first: the opt-out may come from a stale view.
-                self.update()
-                if registered or self._n_reservable:
-                    # The worker can make progress without new points —
-                    # consume what is already registered (this round's
-                    # partial batch or a concurrent producer's); exhaustion
-                    # re-fires on the next dry production round.
-                    break
-                if self._n_in_flight > own_in_flight:
-                    # Executing trials beyond the caller's own exist; their
-                    # completions may change the algorithm's state — wait.
-                    self._sleep_backoff()
-                    continue
-                t0 = time.perf_counter()
-                batch = self.naive_algorithm.suggest_batch(
-                    pool_size - registered
-                )
-                suggested = batch.params if batch is not None else None
-                self.algorithm.rng_key = self.naive_algorithm.rng_key
-                if suggested is None:
-                    # Nothing pending, nothing running, and a fresh-state
-                    # retry still opts out: no observation can ever arrive,
-                    # so the state producing this opt-out is final.
-                    raise AlgorithmExhausted(
-                        "algorithm opted out of suggesting with no trials "
-                        "in flight; the search space is exhausted"
-                    )
-                self._record_timing(
-                    "suggest", time.perf_counter() - t0, len(suggested)
-                )
-            # Columnar commit: the round's chunk stays a lazy ParamBatch
-            # (or a host scheduler's dict list) wrapped by a TrialBatch —
-            # ids and storage documents are built in ONE columnar pass
-            # (core.trial), never q Trial constructions.  Trial objects
-            # materialize only at the plugin-compat boundary below
-            # (speculative lie conditioning, register_suggestion overrides).
-            batch = TrialBatch(suggested[: pool_size - registered])
-            # Pipelined commit (the storage twin of speculative suggest):
-            # when this batch fills the round, stamp identities now —
-            # freezing ids, so the speculative lie path and cube cache key
-            # correctly — top the speculative ring up to pipeline_depth
-            # in-flight rounds, and only then write storage, so the commit
-            # overlaps jax async dispatch instead of serializing with it.
-            # Presuming the batch registers is safe: a slot that turns out
-            # duplicate IS durably registered (by whoever won the race), so
-            # the speculative conditioning stays truthful; the ring is
-            # discarded below if any slot fails to register.
-            prepared = registered + len(batch) >= pool_size
-            overlapped = False
-            if prepared:
-                self.experiment.prepare_trial_batch(batch, parents=self._leaf_ids)
-                if getattr(self.naive_algorithm, "speculation_safe", False):
-                    overlapped = self._dispatch_speculative(
-                        pool_size, registered_trials + batch.trials()
-                    )
-            # Batch registration: ONE storage round — a single transaction
-            # on SQL backends, one wire request on the network driver
-            # (q=4096 would otherwise pay q serialized RTTs); per-trial
-            # DuplicateKeyError comes back as that slot's outcome.
-            t0 = time.perf_counter()
-            try:
-                outcomes = self.experiment.register_trial_batch(
-                    batch, parents=self._leaf_ids, prepared=prepared
-                )
-            except Exception:
-                if overlapped:
-                    # Transport-level commit failure (no per-slot outcomes):
-                    # the batch's fate is unknown, so every ring entry
-                    # conditioned on it must go — same contract as the
-                    # per-slot discard below.
-                    self._discard_spec_ring()
-                raise
-            self._record_timing("register", time.perf_counter() - t0, len(batch))
-            had_duplicate = False
-            batch_error = None
-            spec_capable = getattr(self.naive_algorithm, "speculation_safe", False)
-            # lint: disable=PERF001 -- per-slot outcome handling: the
-            # register_suggestion hook is a per-point plugin API (gated to
-            # algorithms that actually override it), everything else here
-            # is O(1) bookkeeping per slot.
-            for slot, outcome in enumerate(outcomes):
-                if isinstance(outcome, DuplicateKeyError):
-                    # The point IS durably registered (by us earlier or by a
-                    # concurrent worker) — the algorithm must still learn it
-                    # is consumed, or it will re-suggest it forever.
-                    if self._needs_register_suggestion:
-                        self.algorithm.register_suggestion(batch.params[slot])
-                    log.debug("duplicate suggestion %s", batch.ids[slot])
-                    had_duplicate = True
-                elif isinstance(outcome, Exception):
-                    # Remember but keep walking the outcomes: later slots of
-                    # the same pipelined round trip WERE durably registered,
-                    # and skipping their register_suggestion would make the
-                    # algorithm re-suggest them all next round.
-                    batch_error = batch_error or outcome
-                else:
-                    if self._needs_register_suggestion:
-                        self.algorithm.register_suggestion(batch.params[slot])
-                    registered += 1
-                    # Trial views only materialize for the speculative
-                    # conditioning path; their ids ride the columnar batch
-                    # (no md5 recomputation — the cube cache keys on them).
-                    if spec_capable:
-                        registered_trials.append(batch.trial_at(slot))
-            if overlapped and (had_duplicate or batch_error is not None):
-                # The speculative copies were conditioned on slots that did
-                # not register; drop the whole ring — the post-loop dispatch
-                # (or the next round's) redoes it from the true set.
-                self._discard_spec_ring()
-            if batch_error is not None:
-                raise batch_error
-            if had_duplicate:
-                self.backoff()
+                speculative = None
+            if outcome is None:
+                break
+            registered, retry = outcome
         self._round_index += 1
         if TELEMETRY.enabled:
             # One optimization-health record per round (orion_tpu.health):
@@ -647,6 +499,157 @@ class Producer:
         ):
             self._dispatch_speculative(pool_size, registered_trials)
         return registered
+
+    def _produce_pass(self, pool_size, own_in_flight, registered,
+                      registered_trials, start, speculative):
+        """One suggest-and-register pass of a produce round.  Returns the
+        round's registered count and whether a slot was a duplicate, or
+        None when the round should end with what it has."""
+        if time.time() - start > self.max_idle_time:
+            raise SampleTimeout(
+                f"algorithm produced no new unique point in {self.max_idle_time}s"
+            )
+        if speculative is not None:
+            # Already timed by _take_speculative (the residual transfer).
+            suggested = speculative
+        else:
+            # Columnar flow: the suggestion crosses the boundary as a (q, d)
+            # array; batch.params is a LAZY ParamBatch — the storage
+            # documents build straight from its columns, and per-point dicts
+            # only materialize at plugin-compat boundaries
+            # (register_suggestion overrides, lie strategy).
+            suggested = self._suggest_from_naive(pool_size - registered)
+        if suggested is None:
+            log.debug("algorithm opted out of suggesting")
+            # Re-sync first: the opt-out may come from a stale view.
+            self.update()
+            if registered or self._n_reservable:
+                # The worker can make progress without new points — consume
+                # what is already registered (this round's partial batch or
+                # a concurrent producer's); exhaustion re-fires on the next
+                # dry production round.
+                return None
+            if self._n_in_flight > own_in_flight:
+                # Executing trials beyond the caller's own exist; their
+                # completions may change the algorithm's state — wait.
+                self._sleep_backoff()
+                return registered, False
+            suggested = self._suggest_from_naive(pool_size - registered)
+            if suggested is None:
+                # Nothing pending, nothing running, and a fresh-state retry
+                # still opts out: no observation can ever arrive, so the
+                # state producing this opt-out is final.
+                raise AlgorithmExhausted(
+                    "algorithm opted out of suggesting with no trials "
+                    "in flight; the search space is exhausted"
+                )
+        return self._register_batch(suggested, pool_size, registered, registered_trials)
+
+    def _suggest_from_naive(self, num):
+        """One suggest of ``num`` rows from the naive copy: its params (a
+        lazy ParamBatch), or None when the algorithm opts out."""
+        t0 = time.perf_counter()
+        with TELEMETRY.span("producer.suggest", args={"count": num}):
+            batch = self.naive_algorithm.suggest_batch(num)
+        suggested = batch.params if batch is not None else None
+        # Advance ONLY the real algo's RNG stream, never its full state:
+        # the naive copy has observed fantasy lies, and syncing its whole
+        # state_dict would permanently inject those rows into the real
+        # algorithm (compounding every round).
+        self.algorithm.rng_key = self.naive_algorithm.rng_key
+        if suggested is not None:
+            self._record_timing("suggest", time.perf_counter() - t0, len(suggested))
+        return suggested
+
+    def _register_batch(self, suggested, pool_size, registered, registered_trials):
+        """Register one pass's suggestions; returns the round's registered
+        count and whether any slot was a duplicate (the caller then backs
+        off and retries)."""
+        # Columnar commit: the round's chunk stays a lazy ParamBatch
+        # (or a host scheduler's dict list) wrapped by a TrialBatch —
+        # ids and storage documents are built in ONE columnar pass
+        # (core.trial), never q Trial constructions.  Trial objects
+        # materialize only at the plugin-compat boundary below
+        # (speculative lie conditioning, register_suggestion overrides).
+        batch = TrialBatch(suggested[: pool_size - registered])
+        # Pipelined commit (the storage twin of speculative suggest):
+        # when this batch fills the round, stamp identities now —
+        # freezing ids, so the speculative lie path and cube cache key
+        # correctly — top the speculative ring up to pipeline_depth
+        # in-flight rounds, and only then write storage, so the commit
+        # overlaps jax async dispatch instead of serializing with it.
+        # Presuming the batch registers is safe: a slot that turns out
+        # duplicate IS durably registered (by whoever won the race), so
+        # the speculative conditioning stays truthful; the ring is
+        # discarded below if any slot fails to register.
+        prepared = registered + len(batch) >= pool_size
+        overlapped = False
+        if prepared:
+            self.experiment.prepare_trial_batch(batch, parents=self._leaf_ids)
+            if getattr(self.naive_algorithm, "speculation_safe", False):
+                overlapped = self._dispatch_speculative(
+                    pool_size, registered_trials + batch.trials()
+                )
+        # Batch registration: ONE storage round — a single transaction
+        # on SQL backends, one wire request on the network driver
+        # (q=4096 would otherwise pay q serialized RTTs); per-trial
+        # DuplicateKeyError comes back as that slot's outcome.
+        t0 = time.perf_counter()
+        try:
+            with TELEMETRY.span("producer.register", args={"count": len(batch)}):
+                outcomes = self.experiment.register_trial_batch(
+                    batch, parents=self._leaf_ids, prepared=prepared
+                )
+        except Exception:
+            if overlapped:
+                # Transport-level commit failure (no per-slot outcomes):
+                # the batch's fate is unknown, so every ring entry
+                # conditioned on it must go — same contract as the
+                # per-slot discard below.
+                self._discard_spec_ring()
+            raise
+        self._record_timing("register", time.perf_counter() - t0, len(batch))
+        duplicates = 0
+        batch_error = None
+        spec_capable = getattr(self.naive_algorithm, "speculation_safe", False)
+        # lint: disable=PERF001 -- per-slot outcome handling: the
+        # register_suggestion hook is a per-point plugin API (gated to
+        # algorithms that actually override it), everything else here
+        # is O(1) bookkeeping per slot.
+        for slot, outcome in enumerate(outcomes):
+            if isinstance(outcome, DuplicateKeyError):
+                # The point IS durably registered (by us earlier or by a
+                # concurrent worker) — the algorithm must still learn it
+                # is consumed, or it will re-suggest it forever.
+                if self._needs_register_suggestion:
+                    self.algorithm.register_suggestion(batch.params[slot])
+                log.debug("duplicate suggestion %s", batch.ids[slot])
+                duplicates += 1
+            elif isinstance(outcome, Exception):
+                # Remember but keep walking the outcomes: later slots of
+                # the same pipelined round trip WERE durably registered,
+                # and skipping their register_suggestion would make the
+                # algorithm re-suggest them all next round.
+                batch_error = batch_error or outcome
+            else:
+                if self._needs_register_suggestion:
+                    self.algorithm.register_suggestion(batch.params[slot])
+                registered += 1
+                # Trial views only materialize for the speculative
+                # conditioning path; their ids ride the columnar batch
+                # (no md5 recomputation — the cube cache keys on them).
+                if spec_capable:
+                    registered_trials.append(batch.trial_at(slot))
+        if duplicates:
+            TELEMETRY.count("producer.duplicate_rows", duplicates)
+        if overlapped and (duplicates or batch_error is not None):
+            # The speculative copies were conditioned on slots that did
+            # not register; drop the whole ring — the post-loop dispatch
+            # (or the next round's) redoes it from the true set.
+            self._discard_spec_ring()
+        if batch_error is not None:
+            raise batch_error
+        return registered, duplicates > 0
 
     def _build_health(self, registered):
         """Merge naive-copy device health over real-instance host truth
@@ -818,7 +821,8 @@ class Producer:
         handle, algo, t0, ctx = self._spec_ring.popleft()
         try:
             t_fin = time.perf_counter()
-            out = algo.finalize_suggest_batch(handle).params[:pool_size]
+            with TELEMETRY.span("producer.suggest", args={"count": pool_size}):
+                out = algo.finalize_suggest_batch(handle).params[:pool_size]
             # Timed as "suggest": what remains of the device round trip
             # after the overlap (ideally just the residual transfer).
             self._record_timing("suggest", time.perf_counter() - t_fin, len(out))

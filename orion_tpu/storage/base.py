@@ -18,11 +18,7 @@ from orion_tpu.health import FLIGHT
 from orion_tpu.storage.backends import PickledDB
 from orion_tpu.storage.documents import MemoryDB
 from orion_tpu.storage.retry import MODE_ALWAYS, MODE_UNAPPLIED, create_retry_policy
-from orion_tpu.telemetry import (
-    TELEMETRY,
-    current_trace_context,
-    set_trace_context,
-)
+from orion_tpu.telemetry import TELEMETRY
 from orion_tpu.utils.exceptions import DatabaseError, FailedUpdate
 
 
@@ -230,34 +226,21 @@ def _traced(op, span_name=None, retry=MODE_ALWAYS):
                     return fn(self, *args, **kwargs)
             if not TELEMETRY.enabled:
                 return run()
+            backend = self._backend_label
             t0 = time.perf_counter()
-            # Run the op AS a child trace context: wire drivers underneath
-            # (NetworkDB) inject the ambient context into their request
-            # envelopes, so the server's apply span parents at THIS op span
-            # (storage.commit -> netdb.apply in the distributed merge).
-            parent = current_trace_context()
-            ctx = parent.child() if parent is not None and parent.sampled else None
-            if ctx is not None:
-                set_trace_context(ctx)
+            # The op runs AS a child trace context (the span installs it as
+            # the ambient): wire drivers underneath (NetworkDB) inject it
+            # into their request envelopes, so the server's apply span
+            # parents at THIS op span (storage.commit -> netdb.apply in the
+            # distributed merge).  histogram=False: the sample's ONE
+            # histogram home is the per-backend key below — same-name span
+            # histograms would double every snapshot's payload and
+            # duplicate info rows.
             try:
-                return run()
+                with TELEMETRY.span(name, args={"backend": backend}, histogram=False):
+                    return run()
             finally:
-                if ctx is not None:
-                    set_trace_context(parent)
-                duration = time.perf_counter() - t0
-                backend = self._backend_label
-                # histogram=False: the sample's ONE histogram home is the
-                # per-backend key below — same-name span histograms would
-                # double every snapshot's payload and duplicate info rows.
-                TELEMETRY.record_span(
-                    name,
-                    start=t0,
-                    args={"backend": backend},
-                    histogram=False,
-                    span_ctx=ctx,
-                    parent_ctx=parent if ctx is not None else None,
-                )
-                TELEMETRY.observe(f"storage.{backend}.{op}", duration)
+                TELEMETRY.observe(f"storage.{backend}.{op}", time.perf_counter() - t0)
 
         return wrapper
 

@@ -23,12 +23,22 @@ never raise into a hot path.  Mutators swallow their own failures; only
 the explicit exporters propagate I/O errors.
 
 Cross-worker story: each worker flushes ``snapshot()`` (metrics) and
-``drain_spans()`` (new span records) through the storage channel
+``drain_spans("storage")`` (new span records, on the storage channel's own
+cursor) through the storage channel
 (``DocumentStorage.record_metrics`` / ``record_spans``) every producer
 round; ``orion-tpu info`` merges the snapshots with
 :func:`merge_snapshots`, and ``orion-tpu trace`` merges every worker's
 spans into one Chrome trace (span timestamps are wall-anchored monotonic
 readings, so processes line up on a shared timeline).
+
+Profiler clock: while the registry is enabled, a ``with TELEMETRY.span(name)``
+also opens a ``jax.profiler.TraceAnnotation(name)`` for its body, so the
+span is written on the profiler's host plane, on the device trace's
+timeline, whenever a profiler session is active (``hunt --profile``, a
+traced benchmark run).  Only ``with`` spans reach the profiler trace: a
+span booked after the fact through :meth:`Telemetry.record_span` lives in
+the ring alone.  jax is taken from ``sys.modules``, so a process that never
+imported it (a netdb server) imports nothing here.
 
 Distributed tracing: a :class:`TraceContext` (128-bit ``trace_id``, 64-bit
 ``span_id``, ``sampled`` flag) rides a thread-local ambient slot.  With
@@ -47,6 +57,7 @@ merged trace draws arrows across process tracks.
 
 import json
 import os
+import sys
 import threading
 import time
 import weakref
@@ -64,7 +75,14 @@ _ENABLE_VALUES = ("1", "on", "true", "yes")
 #: merged snapshots sum buckets elementwise, so every writer must agree.
 N_BUCKETS = 48
 
-DEFAULT_SPAN_CAPACITY = 4096
+#: Span records the ring holds.  Sized so that one 20 s optimize window of
+#: the benchmark (~185 producer rounds of q=256 at ~20-30 spans a round,
+#: more with retries) fits about three times over without wrapping, so an
+#: in-process reader sees every round of it.
+DEFAULT_SPAN_CAPACITY = 1 << 14
+
+#: The cursor :meth:`Telemetry.drain_spans` advances when no consumer is named.
+DEFAULT_CONSUMER = "default"
 
 
 # --- distributed trace context ----------------------------------------------
@@ -180,12 +198,26 @@ class _NullSpan:
     def __exit__(self, exc_type, exc, tb):
         return False
 
+    def rename(self, name):
+        """No record to rename."""
+
 
 _NULL_SPAN = _NullSpan()
 
 
+def _trace_annotation(name):
+    """An open-able ``jax.profiler.TraceAnnotation`` for ``name``, or None
+    in a process that has not imported jax."""
+    jax = sys.modules.get("jax")
+    profiler = getattr(jax, "profiler", None) if jax is not None else None
+    if profiler is None:
+        return None
+    return profiler.TraceAnnotation(name)
+
+
 class _Span:
-    """An enabled span: records itself into the registry on exit.
+    """An enabled span: records itself into the registry on exit, and
+    writes a profiler annotation of the same name around its body.
 
     Trace threading: a ``root=True`` span mints a FRESH :class:`TraceContext`
     (a new distributed trace — the producer round); otherwise, when an
@@ -193,23 +225,41 @@ class _Span:
     itself as the ambient for the body, so nested spans (and wire
     injections inside the body) parent here."""
 
-    __slots__ = ("_telemetry", "name", "args", "_t0", "_root", "_ctx", "_prev")
+    __slots__ = (
+        "_telemetry", "name", "args", "_t0", "_root", "_ctx", "_prev",
+        "_histogram", "_annotation",
+    )
 
-    def __init__(self, telemetry, name, args, root=False):
+    def __init__(self, telemetry, name, args, root=False, histogram=True):
         self._telemetry = telemetry
         self.name = name
         self.args = args
         self._root = root
+        self._histogram = histogram
         self._t0 = None
         self._ctx = None
         self._prev = None
+        self._annotation = None
 
     @property
     def ctx(self):
         """This span's own :class:`TraceContext` (None when untraced)."""
         return self._ctx
 
+    def rename(self, name):
+        """Book the record under ``name`` (an outcome known only inside the
+        body, such as a compile); the profiler annotation keeps the name it
+        was opened with."""
+        self.name = name
+
     def __enter__(self):
+        try:
+            annotation = _trace_annotation(self.name)
+            if annotation is not None:
+                annotation.__enter__()
+                self._annotation = annotation
+        except Exception:  # pragma: no cover - must never raise into hot path
+            self._annotation = None
         self._t0 = time.perf_counter()
         prev = current_trace_context()
         if self._root:
@@ -221,12 +271,20 @@ class _Span:
         return self
 
     def __exit__(self, exc_type, exc, tb):
+        duration = time.perf_counter() - self._t0
+        if self._annotation is not None:
+            try:
+                self._annotation.__exit__(None, None, None)
+            except Exception:  # pragma: no cover - must never raise
+                pass
         if self._ctx is not None:
             set_trace_context(self._prev)
         self._telemetry.record_span(
             self.name,
             start=self._t0,
+            duration=duration,
             args=self.args,
+            histogram=self._histogram,
             span_ctx=self._ctx,
             # A root span STARTS its trace: the enclosing ambient (an
             # embedder's unrelated trace) must not become its parent, or
@@ -274,7 +332,10 @@ class Telemetry:
         self._capacity = max(int(span_capacity), 8)
         self._ring = [None] * self._capacity
         self._seq = 0
-        self._drained = 0
+        # Consumer name -> the seq its next drain starts from.  Each reader
+        # (the producer's storage flush, an in-process benchmark) drains its
+        # own cursor, so no consumer takes spans from another.
+        self._cursors = {}
         # Wall anchor: ts_wall = _anchor + perf_counter reading.  Spans use
         # the monotonic clock for start/duration; the anchor puts every
         # process on one comparable wall timeline at export/merge time.
@@ -405,16 +466,17 @@ class Telemetry:
         return out
 
     # --- spans --------------------------------------------------------------
-    def span(self, name, args=None, root=False):
+    def span(self, name, args=None, root=False, histogram=True):
         """Context manager timing a block.  Disabled: the shared no-op
-        singleton (no allocation, no clock read).  Enabled: records a span
-        AND a duration sample into the histogram of the same name.
-        ``root=True`` starts a NEW distributed trace for the body (the
-        producer-round entry point); otherwise the span becomes a child of
-        any ambient :class:`TraceContext`."""
+        singleton (no allocation, no clock read, no annotation).  Enabled:
+        records a span AND (unless ``histogram=False``) a duration sample
+        into the histogram of the same name, and writes a profiler
+        annotation around the body.  ``root=True`` starts a NEW distributed
+        trace for the body (the producer-round entry point); otherwise the
+        span becomes a child of any ambient :class:`TraceContext`."""
         if not self.enabled:
             return _NULL_SPAN
-        return _Span(self, name, args, root=root)
+        return _Span(self, name, args, root=root, histogram=histogram)
 
     def record_span(
         self,
@@ -432,12 +494,12 @@ class Telemetry:
 
         ``start``/``duration`` are ``time.perf_counter()`` readings/deltas;
         give either or both (a missing start is back-computed from now, a
-        missing duration runs to now).  Callers that already measured a
-        phase (the producer's ``_record_timing``) route through here so the
-        span and its histogram sample come from the same clock reading.
-        ``histogram=False`` records the span only — for call sites that
-        feed a differently-keyed histogram themselves (the storage layer's
-        per-backend op histograms) and must not double-book the sample.
+        missing duration runs to now).  A record booked here after the fact
+        is not on the profiler's clock; a phase the profiler should see
+        runs under :meth:`span`.  ``histogram=False`` records the span only
+        — for call sites that feed a differently-keyed histogram themselves
+        (the storage layer's per-backend op histograms) and must not
+        double-book the sample.
 
         Trace stamping: ``span_ctx`` is this span's OWN identity (its
         ``span_id``), ``parent_ctx`` its parent; pass only ``parent_ctx``
@@ -484,10 +546,7 @@ class Telemetry:
         links=None,
         track=None,
     ):
-        """THE span-record builder — shared by :meth:`record_span` and
-        :meth:`record_spans_batch` so the None-start back-computation and
-        the record schema cannot drift between the per-call and batched
-        paths.  Returns ``(record, duration_seconds)``."""
+        """THE span-record builder.  Returns ``(record, duration_seconds)``."""
         if start is None:
             duration = float(duration or 0.0)
             start = now - duration
@@ -533,45 +592,6 @@ class Telemetry:
             record["worker"] = track
         return record, float(duration)
 
-    def record_spans_batch(self, entries):
-        """Record many finished spans under ONE lock acquisition.
-
-        ``entries`` is ``[(name, start, duration, args), ...]`` with the
-        same semantics as :meth:`record_span` (``start`` a perf_counter
-        reading; a None start is back-computed from ``duration`` against
-        the batch's shared "now").  An optional fifth element carries the
-        :class:`TraceContext` that was ambient when the sample was taken
-        (``parent_ctx`` semantics — buffering must not re-read the ambient
-        at flush time, which may belong to a later round).  The producer
-        buffers its per-sample spans across a round and flushes them here —
-        per-sample ``record_span`` calls each paid a lock round-trip and a
-        clock read inside the hot loop (see ``bench.py``'s
-        ``telemetry_us_saved``)."""
-        if not self.enabled or not entries:
-            return
-        try:
-            now = time.perf_counter()
-            records = [
-                (entry[0],)
-                + self._build_span_record(
-                    entry[0],
-                    entry[1],
-                    entry[2],
-                    entry[3],
-                    now,
-                    parent_ctx=entry[4] if len(entry) > 4 else None,
-                )
-                for entry in entries
-            ]
-            with self._lock:
-                TSAN.write("Telemetry._ring", self)
-                for name, record, duration in records:
-                    self._ring[self._seq % self._capacity] = record
-                    self._seq += 1
-                    self._observe_locked(name, duration)
-        except Exception:  # pragma: no cover - must never raise into hot path
-            pass
-
     def iter_spans(self):
         """Every span currently in the ring, oldest first (wraparound has
         dropped anything older than ``capacity`` records)."""
@@ -580,15 +600,18 @@ class Telemetry:
             start = max(0, self._seq - self._capacity)
             return [self._ring[i % self._capacity] for i in range(start, self._seq)]
 
-    def drain_spans(self):
-        """Spans recorded since the last drain (each span is returned
-        exactly once — the worker flush channel).  Wraparound between
-        drains loses the overwritten oldest records, by design."""
+    def drain_spans(self, consumer=DEFAULT_CONSUMER):
+        """Spans recorded since ``consumer``'s last drain: each span is
+        returned once to each consumer, and one consumer's drain never
+        takes spans from another (the producer's storage flush drains
+        ``"storage"``; in-process readers use the default cursor).
+        Wraparound between drains loses the overwritten oldest records,
+        by design."""
         with self._lock:
             TSAN.write("Telemetry._ring", self)  # advances the drain cursor
-            start = max(self._drained, self._seq - self._capacity)
+            start = max(self._cursors.get(consumer, 0), self._seq - self._capacity)
             out = [self._ring[i % self._capacity] for i in range(start, self._seq)]
-            self._drained = self._seq
+            self._cursors[consumer] = self._seq
             return out
 
     # --- snapshots / merging ------------------------------------------------
@@ -629,7 +652,7 @@ class Telemetry:
             self._external.clear()
             self._ring = [None] * self._capacity
             self._seq = 0
-            self._drained = 0
+            self._cursors.clear()
 
     # --- exporters ----------------------------------------------------------
     def export_jsonl(self, path):

@@ -26,7 +26,6 @@ BREAKDOWN_KEYS = (
     "dict_build",
     "doc_build",
     "storage_ms",
-    "telemetry_us_saved",
     "prep_us_saved",
     "dispatch_us_saved",
 )
@@ -99,16 +98,16 @@ def test_bench_smoke_emits_valid_json_with_breakdown_keys(tmp_path, repo_root):
     # Steady-state host tax, trackable across BENCH_* separately from
     # throughput: the sum of the host stages (everything except
     # wait_transfer, the separately-tracked storage_ms, and the
-    # telemetry_us_saved / prep_us_saved savings reports).
+    # prep_us_saved / dispatch_us_saved savings reports).
     assert payload["host_ms_per_round"] == round(
         sum(v for k, v in breakdown.items()
-            if k not in ("wait_transfer", "storage_ms", "telemetry_us_saved",
-                         "prep_us_saved", "dispatch_us_saved")),
+            if k not in ("wait_transfer", "storage_ms", "prep_us_saved",
+                         "dispatch_us_saved")),
         3,
     )
     # The plan-prep cache (ISSUE 16 satellite): after the first round every
     # fused-plan build must be a cache hit, and the breakdown reports the
-    # saved host microseconds like telemetry_us_saved.
+    # saved host microseconds.
     assert breakdown["prep_us_saved"] >= 0
     # The dispatch-prep token (host-tail endgame): the steady path skips
     # re-validation / statics rebuild entirely and books its savings on
@@ -141,8 +140,7 @@ def test_bench_smoke_emits_valid_json_with_breakdown_keys(tmp_path, repo_root):
     # hard-asserts the same bar before emitting).
     round_ms = sum(
         v for k, v in breakdown.items()
-        if k not in ("storage_ms", "telemetry_us_saved", "prep_us_saved",
-                     "dispatch_us_saved")
+        if k not in ("storage_ms", "prep_us_saved", "dispatch_us_saved")
     )
     assert breakdown["health"] <= 0.01 * round_ms
     # The optimization-health payload: a real per-round regret curve with

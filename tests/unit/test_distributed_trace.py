@@ -149,21 +149,6 @@ def test_trace_scope_adopts_and_restores():
         set_trace_context(None)
 
 
-def test_batched_entries_carry_captured_context():
-    t = Telemetry(enabled=True)
-    ctx = TraceContext()
-    t.record_spans_batch(
-        [
-            ("old.style", None, 0.001, None),
-            ("with.ctx", None, 0.002, {"count": 1}, ctx),
-        ]
-    )
-    old, new = t.iter_spans()
-    assert "trace_id" not in old
-    assert new["trace_id"] == ctx.trace_id
-    assert new["parent_span_id"] == ctx.span_id
-
-
 def test_chrome_flow_events_cross_track_and_links():
     parent = TraceContext()
     spans = [

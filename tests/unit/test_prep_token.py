@@ -3,18 +3,17 @@
 The token may only ever be a shortcut — a run with the token disabled
 (``algo._prep_token = None`` forces the full prep-key probe every round)
 must produce a bit-identical suggestion stream, because both paths feed
-the SAME ``_finish_plan`` tail.  And the stats it feeds the bench's
-``dispatch_us_saved`` line must count what actually happened: one miss to
-pin a bucket, hits while the bucket holds, a fresh miss when the fast key
-changes (q bucket, cold→warm flip).
+the SAME ``_finish_plan`` tail.  And the telemetry counters it feeds (the
+bench's ``dispatch_us_saved`` line reads them) must count what actually
+happened: one miss to pin a bucket, hits while the bucket holds, a fresh
+miss when the fast key changes (q bucket, cold→warm flip).
 """
 
+import pytest
+
 from orion_tpu.algo.base import create_algo
-from orion_tpu.algo.tpu_bo import (
-    dispatch_prep_stats,
-    reset_dispatch_prep_stats,
-)
 from orion_tpu.space.dsl import build_space
+from orion_tpu.telemetry import TELEMETRY
 
 CFG = {"tpu_bo": {"n_init": 4, "n_candidates": 64, "fit_steps": 2,
                    "refit_steps": 1}}
@@ -54,21 +53,32 @@ def test_token_fast_path_is_bit_identical_to_full_probe():
     assert fast._prep_token.pinned is not None  # the fast path was live
 
 
-def test_dispatch_prep_stats_count_pin_hold_and_rekey():
+@pytest.fixture
+def telemetry_on():
+    was = TELEMETRY.enabled
+    TELEMETRY.enable()
+    yield
+    if not was:
+        TELEMETRY.disable()
+
+
+def _token_counts(since=(0, 0)):
+    """(misses, hits) of the prep token since ``since``."""
+    return (TELEMETRY.counter_value("plan.token.misses") - since[0],
+            TELEMETRY.counter_value("plan.token.hits") - since[1])
+
+
+def test_dispatch_prep_stats_count_pin_hold_and_rekey(telemetry_on):
     algo = _warm_algo(token=True)
-    reset_dispatch_prep_stats()
+    start = _token_counts()
     algo.suggest(4)  # cold fit: miss, pins (bucket 8, warm_is_none=True)
     algo.suggest(4)  # warm now — fast key flipped: miss, re-pins
     algo.suggest(4)  # steady path
     algo.suggest(4)
-    stats = dispatch_prep_stats()
-    assert stats["misses"] == 2
-    assert stats["hits"] == 2
+    assert _token_counts(start) == (2, 2)
     algo.suggest(16)  # q bucket 8 -> 16: the token must not lie
-    stats = dispatch_prep_stats()
-    assert stats["misses"] == 3
-    assert stats["saved_us"] >= 0.0
-    # The breakdown line's inputs are all present and well-formed.
-    assert set(stats) == {
-        "hits", "misses", "hit_us_mean", "miss_us_mean", "saved_us"
-    }
+    assert _token_counts(start) == (3, 2)
+    # Every plan is timed by its ``algo.plan`` span: the counters' time
+    # lives in that histogram, not in a side channel of its own.
+    plan = TELEMETRY.snapshot()["histograms"]["algo.plan"]
+    assert plan["count"] >= 5

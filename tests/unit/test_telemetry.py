@@ -246,44 +246,6 @@ def test_span_flush_through_storage_channel_with_cap(monkeypatch):
     assert [d["name"] for d in docs][-3:] == ["late"] * 3
 
 
-def test_record_spans_batch_matches_per_call_semantics():
-    """One batched call books the same ring records and histogram samples
-    as N record_span calls (the producer's hot-loop batching, PR 7)."""
-    t = tel.Telemetry(enabled=True, span_capacity=64)
-    entries = [
-        ("producer.suggest", None, 0.001, {"count": 4}),
-        ("producer.observe", None, 0.002, None),
-        ("producer.register", None, 0.004, {"count": 4}),
-    ]
-    t.record_spans_batch(entries)
-    spans = t.iter_spans()
-    assert [s["name"] for s in spans] == [
-        "producer.suggest",
-        "producer.observe",
-        "producer.register",
-    ]
-    assert spans[0]["args"] == {"count": 4}
-    assert "args" not in spans[1]
-    snap = t.snapshot()
-    for name in ("producer.suggest", "producer.observe", "producer.register"):
-        assert snap["histograms"][name]["count"] == 1
-    assert snap["histograms"]["producer.register"]["sum"] == pytest.approx(0.004)
-    # Explicit starts are honored (the producer stamps now - duration at
-    # sample time so batching does not shift the trace timeline).
-    import time as _time
-
-    start = _time.perf_counter() - 0.5
-    t.record_spans_batch([("late", start, 0.25, None)])
-    late = t.iter_spans()[-1]
-    assert late["dur"] == pytest.approx(0.25)
-
-
-def test_record_spans_batch_disabled_is_noop():
-    t = tel.Telemetry(enabled=False)
-    t.record_spans_batch([("x", None, 0.1, None)])
-    assert t.iter_spans() == []
-
-
 # --- end-to-end: producer rounds populate the channel -----------------------
 @pytest.mark.filterwarnings("ignore")
 def test_producer_rounds_flush_spans_and_metrics():

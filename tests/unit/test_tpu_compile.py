@@ -92,7 +92,12 @@ def test_hartmann6_suggest_step_compiles_with_fused_gram(one_chip, monkeypatch):
     finally:
         gram.pallas_enabled.cache_clear()
         jax.clear_caches()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # The benchmark finds the step by its module name and the gram by its
+    # op name; the stage scopes ride the ops' metadata, not their names.
+    assert text.startswith("HloModule jit__suggest_step")
+    assert "%fused_gram" in text and 'op_name="jit(_suggest_step)/posterior/' in text
     memory = compiled.memory_analysis()
     # Small next to the chip's 16 GB: a regression that materializes the
     # (pool, n) gram in f32 several times over shows up here first.
