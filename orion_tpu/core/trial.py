@@ -8,6 +8,7 @@ between workers; device code never sees them (it sees the flat arrays the
 Space codec produces from their params).
 """
 
+import copy
 import hashlib
 import time
 from dataclasses import dataclass
@@ -231,6 +232,23 @@ class Trial:
         doc = dict(doc)
         doc.pop("exp_working_dir", None)
         return cls(**doc)
+
+    @classmethod
+    def from_stored(cls, doc):
+        """:meth:`from_dict` for a document the store lends uncopied (a
+        backend with ``shares_documents``).  ``__init__`` already copies the
+        params dict, the parents list and each result; a non-scalar value
+        inside them (a shaped param, a gradient) is copied here, so the
+        trial shares no mutable object with the store."""
+        trial = cls.from_dict(doc)
+        params = trial.params
+        for name, value in params.items():
+            if type(value) not in _PLAIN_SCALARS:
+                params[name] = copy.deepcopy(value)
+        for result in trial.results:
+            if type(result.value) not in _PLAIN_SCALARS:
+                result.value = copy.deepcopy(result.value)
+        return trial
 
     # --- misc ---------------------------------------------------------------
     @property

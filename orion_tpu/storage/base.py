@@ -193,7 +193,12 @@ _BACKEND_COUNTER_ATTRS = (
     "failovers",
     "replica_stale_reads",
     "fan_outs",
+    "docs_copied",
+    "docs_shared",
 )
+
+#: Read kwargs of the trial ops on a backend with ``shares_documents``.
+_BORROW = {"shared": True}
 
 
 def _traced(op, span_name=None, retry=MODE_ALWAYS):
@@ -291,6 +296,16 @@ class DocumentStorage(BaseStorage):
     def db(self):
         return self._db
 
+    def _trial_reads(self):
+        """``(read kwargs, doc -> Trial)`` for the trial ops that turn what
+        they read into Trials at once.  A backend that advertises
+        ``shares_documents`` lends its stored documents uncopied and
+        :meth:`Trial.from_stored` copies what the trial keeps; any other
+        backend hands out copies, read as before."""
+        if getattr(self._db, "shares_documents", False):
+            return _BORROW, Trial.from_stored
+        return {}, Trial.from_dict
+
     def _setup_indexes(self):
         # Reference `legacy.py:70-88`; batched into one backend write cycle.
         try:
@@ -379,8 +394,9 @@ class DocumentStorage(BaseStorage):
         """Atomically claim one pending trial (the cross-worker sync point;
         reference `legacy.py:253-273`)."""
         query, update = self._reservation_ops(experiment)
-        doc = self._db.read_and_write("trials", query, update)
-        return Trial.from_dict(doc) if doc else None
+        kw, to_trial = self._trial_reads()
+        doc = self._db.read_and_write("trials", query, update, **kw)
+        return to_trial(doc) if doc else None
 
     def _db_batch_capable(self):
         """True when the backend offers a batching primitive — THE
@@ -417,15 +433,16 @@ class DocumentStorage(BaseStorage):
         if not self._db_batch_capable():
             return super().reserve_trials(experiment, num)
         query, update = self._reservation_ops(experiment)
+        kw, to_trial = self._trial_reads()
         # Probe with ONE claim first: callers reserve-then-produce, so the
         # common steady state is an EMPTY queue — batching num futile
         # find-one-and-updates there would double the server's reservation
         # work every round.  Non-empty pays one extra round trip.
-        first = self._db.read_and_write("trials", query, update)
+        first = self._db.read_and_write("trials", query, update, **kw)
         if first is None:
             return []
         if num == 1:
-            return [Trial.from_dict(first)]
+            return [to_trial(first)]
         remaining = num - 1
         if getattr(self._db, "cheap_counts", False):
             # Cap the claim batch at what is actually pending: num-1
@@ -437,16 +454,16 @@ class DocumentStorage(BaseStorage):
             # comes from each claim's own CAS.
             remaining = min(remaining, self._db.count("trials", query))
         if remaining <= 0:
-            return [Trial.from_dict(first)]
+            return [to_trial(first)]
         docs = [first] + self._db_batch(
-            [("read_and_write", ["trials", query, update], {})] * remaining
+            [("read_and_write", ["trials", query, update], kw)] * remaining
         )
         out, error = [], None
         for doc in docs:
             if isinstance(doc, Exception):
                 error = error or doc
             elif doc is not None:
-                out.append(Trial.from_dict(doc))
+                out.append(to_trial(doc))
         if error is not None and not out:
             # Nothing claimed + server-side failure: surface it exactly as
             # the per-op path would — treating it as "no trials pending"
@@ -523,6 +540,9 @@ class DocumentStorage(BaseStorage):
             return super().update_completed_trials(pairs)
         outcomes = []
         now = time.time()
+        # Only found / not found is read from each outcome: on a backend
+        # that shares documents the reply is the stored doc, not a copy.
+        kw = self._trial_reads()[0]
         ops = []
         for trial, results in pairs:
             trial.results = list(results)
@@ -539,7 +559,7 @@ class DocumentStorage(BaseStorage):
                             "status": "completed",
                         },
                     ],
-                    {},
+                    kw,
                 )
             )
         docs = self._db_batch(ops)
@@ -558,9 +578,10 @@ class DocumentStorage(BaseStorage):
     @_traced("fetch_trials", retry=MODE_ALWAYS)
     def fetch_trials(self, experiment=None, uid=None):
         query = {"experiment": uid if uid is not None else _exp_id(experiment)}
-        docs = self._db.read("trials", query)
+        kw, to_trial = self._trial_reads()
+        docs = self._db.read("trials", query, **kw)
         docs.sort(key=_trial_doc_order)
-        return [Trial.from_dict(d) for d in docs]
+        return [to_trial(d) for d in docs]
 
     @_retrying("read_trial_docs", mode=MODE_ALWAYS)
     def read_trial_docs(self, uid, ids=None, projection=None):
@@ -601,10 +622,11 @@ class DocumentStorage(BaseStorage):
         exp_id = _exp_id(experiment)
         noncompleted_query = {"experiment": exp_id, "status": {"$ne": "completed"}}
         completed_query = {"experiment": exp_id, "status": "completed"}
+        kw, to_trial = self._trial_reads()
         if self._db_batch_capable():
             nc_docs, n_completed = self._db_batch(
                 [
-                    ("read", ["trials", noncompleted_query], {}),
+                    ("read", ["trials", noncompleted_query], kw),
                     ("count", ["trials", completed_query], {}),
                 ]
             )
@@ -612,31 +634,34 @@ class DocumentStorage(BaseStorage):
                 if isinstance(result, Exception):
                     raise result
         else:
-            nc_docs = self._db.read("trials", noncompleted_query)
+            nc_docs = self._db.read("trials", noncompleted_query, **kw)
             n_completed = self._db.count("trials", completed_query)
         if n_completed != known_completed:
-            done_docs = self._db.read("trials", completed_query)
+            done_docs = self._db.read("trials", completed_query, **kw)
         else:
             done_docs = []
         by_id = {d["_id"]: d for d in nc_docs}
         by_id.update((d["_id"], d) for d in done_docs)  # completed view wins
         docs = sorted(by_id.values(), key=_trial_doc_order)
-        return [Trial.from_dict(d) for d in docs], n_completed
+        return [to_trial(d) for d in docs], n_completed
 
     @_retrying("fetch_trials_by_status", mode=MODE_ALWAYS)
     def fetch_trials_by_status(self, experiment, status):
         statuses = [status] if isinstance(status, str) else list(status)
+        kw, to_trial = self._trial_reads()
         docs = self._db.read(
             "trials",
             {"experiment": _exp_id(experiment), "status": {"$in": statuses}},
+            **kw,
         )
-        return [Trial.from_dict(d) for d in docs]
+        return [to_trial(d) for d in docs]
 
     @_retrying("get_trial", mode=MODE_ALWAYS)
     def get_trial(self, trial=None, uid=None):
         _id = uid if uid is not None else trial.id
-        docs = self._db.read("trials", {"_id": _id})
-        return Trial.from_dict(docs[0]) if docs else None
+        kw, to_trial = self._trial_reads()
+        docs = self._db.read("trials", {"_id": _id}, **kw)
+        return to_trial(docs[0]) if docs else None
 
     @_traced("set_trial_status", retry=MODE_UNAPPLIED)
     def set_trial_status(self, trial, status, was=None):

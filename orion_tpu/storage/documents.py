@@ -187,6 +187,24 @@ def _copy_doc(value):
     return copy.deepcopy(value)
 
 
+def _copy_doc_once(doc, memo):
+    """`_copy_doc` of one document of a batch insert, copying each top-level
+    sub-object once per batch: ``memo`` maps ``id(original)`` to
+    ``(original, copy)`` (the original is held, so its id cannot be reused
+    mid-batch).  Documents handed in sharing one object (a round's
+    ``parents`` list) are stored sharing one copy — safe because a stored
+    document is never mutated in place (see `apply_update`)."""
+    out = {}
+    for key, value in doc.items():
+        if type(value) not in _SCALAR_TYPES:
+            hit = memo.get(id(value))
+            if hit is None:
+                hit = memo[id(value)] = (value, _copy_doc(value))
+            value = hit[1]
+        out[key] = value
+    return out
+
+
 def _project(nested_doc, projection):
     """Inclusion-style projection walking dotted paths directly — documents
     with literal "." in keys are returned byte-identical, never restructured."""
@@ -214,9 +232,10 @@ def apply_update(doc, update):
     Copy-on-write along the updated paths only: the returned doc SHARES
     every unmodified subtree with ``doc``.  That is safe because every
     caller replaces the stored doc with the result and discards the old one
-    (reads hand out `_copy_doc`/`_project` copies, and indexes reference
-    `_id`s, not subtrees) — and it is what keeps a 2-field status update
-    from deep-copying a several-hundred-node trial document (a 2048-trial
+    (a stored doc is immutable: public reads hand out `_copy_doc`/`_project`
+    copies, borrowed reads hand out docs nobody mutates, and indexes
+    reference `_id`s, not subtrees) — and it is what keeps a 2-field status
+    update from deep-copying a several-hundred-node trial document (a 2048-trial
     ackley50 sweep spends ~35% of its host wall in `_copy_doc` otherwise,
     most of it under updates).
 
@@ -238,7 +257,7 @@ def apply_update(doc, update):
             # update already copied is redundant but harmless.
             node[part] = dict(child) if isinstance(child, dict) else {}
             node = node[part]
-        node[parts[-1]] = _copy_doc(value)
+        node[parts[-1]] = value if type(value) in _SCALAR_TYPES else _copy_doc(value)
     for key in unsets:
         parts = key.split(".")
         # Read-only probe first: an absent final key must stay an
@@ -379,8 +398,14 @@ class Collection:
                     del entries[key]  # maps must not grow with history
 
     # --- CRUD --------------------------------------------------------------
-    def insert(self, doc):
-        doc = _copy_doc(doc)
+    def insert(self, doc, memo=None):
+        """Store a copy of ``doc``; ``memo`` (one per batch) lets the batch's
+        documents share one copy of a sub-object they share
+        (`_copy_doc_once`)."""
+        if memo is None or type(doc) is not dict:
+            doc = _copy_doc(doc)
+        else:
+            doc = _copy_doc_once(doc, memo)
         if "_id" not in doc:
             self._auto_id += 1
             doc["_id"] = self._auto_id
@@ -444,7 +469,11 @@ class Collection:
         ids.update(entries.get(_Unhashable, {}))
         return [self._docs[i] for i in ids if i in self._docs]
 
-    def find(self, query=None, projection=None):
+    def find(self, query=None, projection=None, shared=False):
+        """Matching docs, copied (projected); ``shared`` returns the stored
+        docs themselves (MemoryDB.read)."""
+        if shared:
+            return [doc for doc in self._candidates(query) if _matches(doc, query)]
         out = []
         for doc in self._candidates(query):
             if _matches(doc, query):
@@ -468,8 +497,9 @@ class Collection:
                 break
         return count
 
-    def find_one_and_update(self, query, update, return_new=True):
-        """Atomic single-document compare-and-swap (the sync primitive)."""
+    def find_one_and_update(self, query, update, return_new=True, shared=False):
+        """Atomic single-document compare-and-swap (the sync primitive).
+        ``shared`` returns the stored doc itself instead of a copy."""
         for doc in self._candidates(query):
             if _matches(doc, query):
                 _id = doc["_id"]
@@ -479,7 +509,8 @@ class Collection:
                 self._index_discard(doc)
                 self._docs[_id] = new_doc
                 self._index_add(new_doc)
-                return _copy_doc(new_doc if return_new else doc)
+                out = new_doc if return_new else doc
+                return out if shared else _copy_doc(out)
         return None
 
     def count(self, query=None):
@@ -506,20 +537,33 @@ class MemoryDB:
     #: the producer's count-gated sync keys on this (see Producer.update).
     cheap_counts = True
 
+    #: Stored documents are immutable (every write replaces the stored doc;
+    #: `apply_update` is copy-on-write), so a caller that copies what it
+    #: keeps may borrow them: ``read``/``read_and_write`` with
+    #: ``shared=True`` (also as `apply_batch` sub-op kwargs) return the
+    #: stored docs uncopied.  DocumentStorage's trial ops key on this.
+    shares_documents = True
+
     def __init__(self):
         self._collections = {}
         self._lock = threading.RLock()
+        # Documents deep-copied into (inserts) or out of (reads, CAS
+        # replies) the store, and documents handed out uncopied.
+        self.docs_copied = 0
+        self.docs_shared = 0
 
     def __getstate__(self):
-        # The RLock is process-local; the pickled backend provides its own
-        # cross-process file lock.
+        # The RLock and the counters are process-local; the pickled backend
+        # provides its own cross-process file lock.
         state = self.__dict__.copy()
-        del state["_lock"]
+        for attr in ("_lock", "docs_copied", "docs_shared"):
+            state.pop(attr, None)
         return state
 
     def __setstate__(self, state):
         self.__dict__.update(state)
         self._lock = threading.RLock()
+        self.docs_copied = self.docs_shared = 0
 
     def _col(self, name):
         if name not in self._collections:
@@ -610,27 +654,50 @@ class MemoryDB:
             if op not in self.BATCH_OPS:
                 raise DatabaseError(f"bad batch op {op!r}")
         out = []
+        memo = {}  # the batch's inserts copy a shared sub-object once
         with self._lock:
             for op, args, kwargs in ops:
                 try:
-                    out.append(getattr(self, f"_{op}_locked")(*args, **kwargs))
+                    if op == "write":
+                        out.append(self._write_locked(*args, memo=memo, **kwargs))
+                    else:
+                        out.append(getattr(self, f"_{op}_locked")(*args, **kwargs))
                 except Exception as exc:
                     out.append(exc)
         return out
 
-    def _write_locked(self, collection, data, query=None):
+    def _write_locked(self, collection, data, query=None, memo=None):
         col = self._col(collection)
         if query is None:
             if isinstance(data, (list, tuple)):
-                return [col.insert(doc) for doc in data]
-            return col.insert(data)
+                memo = {} if memo is None else memo
+                ids = []
+                for doc in data:
+                    ids.append(col.insert(doc, memo))
+                    self.docs_copied += 1
+                return ids
+            _id = col.insert(data, memo)
+            self.docs_copied += 1
+            return _id
         return col.update(query, data, many=True)
 
-    def _read_locked(self, collection, query=None, projection=None):
-        return self._col(collection).find(query, projection)
+    def _read_locked(self, collection, query=None, projection=None, shared=False):
+        shared = shared and not projection
+        docs = self._col(collection).find(query, projection, shared=shared)
+        if shared:
+            self.docs_shared += len(docs)
+        else:
+            self.docs_copied += len(docs)
+        return docs
 
-    def _read_and_write_locked(self, collection, query, data):
-        return self._col(collection).find_one_and_update(query, data)
+    def _read_and_write_locked(self, collection, query, data, shared=False):
+        doc = self._col(collection).find_one_and_update(query, data, shared=shared)
+        if doc is not None:
+            if shared:
+                self.docs_shared += 1
+            else:
+                self.docs_copied += 1
+        return doc
 
     def _count_locked(self, collection, query=None):
         return self._col(collection).count(query)
@@ -638,13 +705,13 @@ class MemoryDB:
     def _remove_locked(self, collection, query=None):
         return self._col(collection).remove(query)
 
-    def read(self, collection, query=None, projection=None):
+    def read(self, collection, query=None, projection=None, shared=False):
         with self._lock:
-            return self._read_locked(collection, query, projection)
+            return self._read_locked(collection, query, projection, shared)
 
-    def read_and_write(self, collection, query, data):
+    def read_and_write(self, collection, query, data, shared=False):
         with self._lock:
-            return self._read_and_write_locked(collection, query, data)
+            return self._read_and_write_locked(collection, query, data, shared)
 
     def count(self, collection, query=None):
         with self._lock:

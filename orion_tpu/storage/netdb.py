@@ -83,6 +83,12 @@ _DB_OPS = frozenset(
 # ping stay per-request).
 _BATCH_OPS = MemoryDB.BATCH_OPS
 
+# Read kwarg a client may not send: ``shared`` lends an in-process store's
+# documents uncopied (MemoryDB.shares_documents), which means nothing once
+# a reply is serialized, and a replica of another backend could not replay
+# it.
+_IN_PROCESS_KWARGS = frozenset({"shared"})
+
 # Ops (and batch sub-ops) that dirty the persisted snapshot.
 _MUTATING_OPS = frozenset(
     {"write", "read_and_write", "remove", "ensure_index", "ensure_indexes",
@@ -295,6 +301,14 @@ def perform_client_handshake(exchange, secret, peer):
         )
 
 
+def _bad_kwargs_reply(kwargs):
+    return {
+        "ok": False,
+        "error": "DatabaseError",
+        "message": f"in-process kwargs {sorted(_IN_PROCESS_KWARGS.intersection(kwargs))}",
+    }
+
+
 class _Handler(socketserver.StreamRequestHandler):
     def handle(self):
         db = self.server.db
@@ -364,6 +378,8 @@ class _Handler(socketserver.StreamRequestHandler):
             method = getattr(db, op)
             args = request.get("args", [])
             kwargs = request.get("kwargs", {})
+            if _IN_PROCESS_KWARGS.intersection(kwargs):
+                return _bad_kwargs_reply(kwargs)
             if op in _MUTATING_OPS:
                 result, seq = self.server.apply_replicated(op, args, kwargs, method)
                 self.server.persist_snapshot()
@@ -423,6 +439,8 @@ class _Handler(socketserver.StreamRequestHandler):
                     }
                 sub_args = list(entry[1]) if len(entry) > 1 and entry[1] else []
                 sub_kwargs = dict(entry[2]) if len(entry) > 2 and entry[2] else {}
+                if _IN_PROCESS_KWARGS.intersection(sub_kwargs):
+                    return _bad_kwargs_reply(sub_kwargs)
                 normalized.append((op, sub_args, sub_kwargs))
         except (TypeError, ValueError, KeyError) as exc:
             # A malformed payload must get a structured refusal, never kill

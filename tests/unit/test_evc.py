@@ -365,10 +365,10 @@ def test_tree_fetcher_incremental_reads_and_adaptation(tmp_path):
     orig_read = storage.db.read
     orig_forward = DimensionAddition.forward
 
-    def counting_read(collection, query=None, projection=None):
+    def counting_read(collection, query=None, projection=None, **kwargs):
         if collection == "trials" and projection is None:
             reads["n"] += 1
-        return orig_read(collection, query=query, projection=projection)
+        return orig_read(collection, query=query, projection=projection, **kwargs)
 
     def counting_forward(self, trials):
         adaptations["n"] += len(trials)

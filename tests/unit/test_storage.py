@@ -1172,6 +1172,149 @@ def test_store_state_immune_to_caller_mutation():
     assert fresh["params"][0]["value"] == 0.5
 
 
+def _borrowing_storage():
+    """Memory storage with two stored trials whose every mutable part is
+    present: a shaped (list-valued) param, a list-valued result, parents."""
+    from orion_tpu.core.trial import Result
+
+    storage = create_storage({"type": "memory"})
+    assert storage.db.shares_documents
+    for i in range(2):
+        storage.register_trial(Trial(
+            experiment="exp-id", params={"x": float(i), "v": [float(i), 2.0]},
+            results=[Result("o", "objective", 1.0), Result("g", "gradient", [0.5, 0.25])],
+            parents=["p0", "p1"],
+        ))
+    return storage
+
+
+@pytest.mark.parametrize(
+    "op", ["reserve_trials", "fetch_update_view", "fetch_trials", "get_trial"]
+)
+def test_borrowed_trials_share_nothing_with_the_store(op):
+    """On a backend that lends its stored documents, the Trials the trial ops
+    build own every mutable part: mutating them leaves the store as it was."""
+    storage = _borrowing_storage()
+    first_id = storage.db.read("trials")[0]["_id"]
+    calls = {
+        "reserve_trials": lambda: storage.reserve_trials("exp-id", 2),
+        "fetch_update_view": lambda: storage.fetch_update_view("exp-id")[0],
+        "fetch_trials": lambda: storage.fetch_trials(uid="exp-id"),
+        "get_trial": lambda: [storage.get_trial(uid=first_id)],
+    }
+    before = storage.db.docs_shared
+    trials = calls[op]()
+    assert trials and storage.db.docs_shared > before  # the borrowed path ran
+    stored = sorted(storage.db.read("trials"), key=lambda d: d["_id"])
+    for trial in trials:
+        trial.params["x"] = -1.0
+        trial.params["v"].append(9.0)
+        trial.params["v"][0] = -1.0
+        trial.results[1].value.append(9.0)
+        trial.results[0].value = -1.0
+        trial.results.append(trial.results[0])
+        trial.parents.append("p9")
+        trial.parents[0] = "hacked"
+    assert sorted(storage.db.read("trials"), key=lambda d: d["_id"]) == stored
+
+
+def test_batch_insert_shares_one_copy_that_public_reads_never_alias():
+    """A batch insert whose documents share one ``parents`` list stores one
+    copy of it, shared by the stored documents; a public read still hands
+    out a copy that aliases neither another read nor the store."""
+    db = MemoryDB()
+    parents = ["a", "b"]
+    docs = [{"_id": i, "params": {"x": float(i)}, "parents": parents} for i in range(3)]
+    assert db.apply_batch([("write", ["c", doc], {}) for doc in docs]) == [0, 1, 2]
+    db.write("c", [{"_id": 3 + i, "parents": parents} for i in range(2)])
+    stored = db._collections["c"]._docs
+    assert stored[0]["parents"] is stored[1]["parents"] is stored[2]["parents"]
+    assert stored[3]["parents"] is stored[4]["parents"]
+    assert stored[0]["parents"] is not parents and stored[0]["params"] is not docs[0]["params"]
+    parents.append("caller")  # the caller's list is not the stored one
+    (one,) = db.read("c", {"_id": 0})
+    (two,) = db.read("c", {"_id": 1})
+    assert one["parents"] == ["a", "b"] and one["parents"] is not two["parents"]
+    one["parents"].append("hacked")
+    assert [d["parents"] for d in db.read("c")] == [["a", "b"]] * 5
+    assert (db.docs_copied, db.docs_shared) == (5 + 2 + 5, 0)
+
+
+def test_network_server_refuses_borrowed_reads():
+    """``shared`` is an in-process read: the network server refuses it on a
+    single op and as a batch sub-op kwarg, before anything applies."""
+    from orion_tpu.storage import DBServer
+    from orion_tpu.storage.netdb import NetworkDB
+    from orion_tpu.utils.exceptions import DatabaseError
+
+    server = DBServer(port=0)
+    host, port = server.serve_background()
+    try:
+        db = NetworkDB(host=host, port=port)
+        db.write("c", {"_id": 1, "st": "new"})
+        with pytest.raises(DatabaseError, match="in-process"):
+            db._call("read_and_write", "c", {"_id": 1}, {"st": "go"}, shared=True)
+        with pytest.raises(DatabaseError, match="in-process"):
+            db.apply_batch([("read", ["c"], {"shared": True})])
+        assert db.read("c") == [{"_id": 1, "st": "new"}]
+        assert server.db.docs_shared == 0
+    finally:
+        server.shutdown()
+        server.server_close()
+
+
+def test_update_completed_trials_reports_vanished_trial(storage):
+    """A completion whose trial left the store is a FailedUpdate slot on every
+    backend, the borrowing memory backend included; the other slots land."""
+    from orion_tpu.core.trial import Result
+
+    for i in range(2):
+        storage.register_trial(new_trial(i))
+    gone, kept = storage.reserve_trials("exp-id", 2)
+    storage.db.remove("trials", {"_id": gone.id})
+    results = [Result("o", "objective", 1.0)]
+    out = storage.update_completed_trials([(gone, results), (kept, results)])
+    assert isinstance(out[0], FailedUpdate)
+    assert out[1] is kept and kept.status == "completed"
+    assert [t.id for t in storage.fetch_trials_by_status("exp-id", "completed")] == [kept.id]
+
+
+def test_client_round_books_document_counters():
+    """One q-round through ExperimentClient on memory storage: the round's
+    reservations and completions borrow their documents (2q shared) and only
+    the inserts copy (q trials plus the round's timing samples); both
+    counters reach the telemetry registry as ``storage.memory.docs_*``."""
+    from orion_tpu.client.experiment import ExperimentClient
+    from orion_tpu.core.experiment import build_experiment
+    from orion_tpu.telemetry import TELEMETRY
+
+    q = 8
+    storage = create_storage({"type": "memory"})
+    experiment = build_experiment(
+        storage, "counters", priors={"x": "uniform(0, 1)", "y": "uniform(0, 1)"},
+        max_trials=4 * q, algorithms={"random": {"seed": 0}}, strategy=None, pool_size=q,
+    ).instantiate(seed=0)
+    client = ExperimentClient(experiment)
+    db = storage.db
+
+    def sample():
+        counters = TELEMETRY.snapshot()["counters"]
+        return (
+            counters["storage.memory.docs_copied"], counters["storage.memory.docs_shared"],
+            db.docs_copied, db.docs_shared, db.count("telemetry"),
+        )
+
+    before = sample()
+    trials = client.suggest(q)
+    client.observe_all(trials, [0.5] * q)
+    registry_copied, registry_shared, copied, shared, timings = (
+        b - a for a, b in zip(before, sample())
+    )
+    assert shared == 2 * q
+    assert copied == q + timings
+    assert (registry_copied, registry_shared) == (copied, shared)
+
+
 def test_reservation_stamps_worker_identity(storage):
     """The reservation CAS must attribute the trial to this host:pid (the
     reference declares Trial.worker but never fills it — we do)."""

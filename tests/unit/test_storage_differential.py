@@ -302,3 +302,55 @@ def test_apply_batch_agrees_across_backends(tmp_path):
     finally:
         server.shutdown()
         server.server_close()
+
+
+def _trial_protocol_run(db, monkeypatch, q=32, rounds=3):
+    """The producer's trial traffic over ``db``: each round registers q trial
+    documents sharing one ``parents`` list (the previous round's ids),
+    reserves them (probe claim + batch), completes them with a list-valued
+    result, then reads the history back through every borrowing op.  The
+    clock is a counter, so two runs write identical documents.  Returns the
+    ``to_dict`` of every trial an op handed back and the final collection."""
+    import itertools
+
+    from orion_tpu.core.trial import Result, TrialBatch
+    from orion_tpu.storage import base
+    from orion_tpu.storage.base import DocumentStorage
+
+    clock = itertools.count(1000.0)
+    monkeypatch.setattr(base.time, "time", lambda: next(clock))
+    storage = DocumentStorage(db)
+    rng = random.Random(7)
+    seen, parents, known = [], [], -1
+    for r in range(rounds):
+        params = [{"/x": rng.random(), "/v": [rng.random(), float(r)]} for _ in range(q)]
+        batch = TrialBatch(params).prepare("e", parents=parents, submit_time=float(r))
+        outcomes = storage.register_trial_docs(batch.to_docs())
+        assert not any(isinstance(o, Exception) for o in outcomes)
+        reserved = storage.reserve_trials("e", q)
+        assert len(reserved) == q
+        seen += reserved
+        pairs = [
+            (t, [Result("o", "objective", float(i)), Result("g", "gradient", [float(i), 1.0])])
+            for i, t in enumerate(reserved)
+        ]
+        seen += storage.update_completed_trials(pairs)
+        view, known = storage.fetch_update_view("e", known)
+        seen += view
+        seen += storage.fetch_trials(uid="e")
+        seen += storage.fetch_trials_by_status("e", "completed")
+        seen.append(storage.get_trial(uid=reserved[0].id))
+        parents = [t.id for t in reserved]
+    state = sorted(db.read("trials"), key=lambda d: d["_id"])
+    return [t.to_dict() for t in seen], state
+
+
+def test_borrowed_trial_ops_match_copying_ops(monkeypatch):
+    """The memory store lending its documents (``shares_documents``) changes
+    no answer: the same q=32 produce / reserve / complete sequence on a store
+    with the capability forced off returns the same trials and leaves the
+    same collection."""
+    lending, copying = MemoryDB(), MemoryDB()
+    copying.shares_documents = False
+    assert _trial_protocol_run(lending, monkeypatch) == _trial_protocol_run(copying, monkeypatch)
+    assert lending.docs_shared > 0 and copying.docs_shared == 0
