@@ -42,11 +42,13 @@ class GPState(NamedTuple):
     y_mean: jnp.ndarray  # ()
     y_std: jnp.ndarray  # ()
     # Optimization-health extras (orion_tpu.health): () marginal
-    # log-likelihood per observation of the final fit, and the packed
+    # log-likelihood per observation of the final fit, the count of its
+    # Adam steps whose loss or gradient was non-finite, and the packed
     # per-round DEVICE_HEALTH_FIELDS vector the fused suggest step attaches
     # via _replace.  Optional (None) so ad-hoc constructions stay valid.
     mll: Optional[jnp.ndarray] = None  # ()
     health: Optional[jnp.ndarray] = None  # (len(DEVICE_HEALTH_FIELDS),)
+    fit_nonfinite: Optional[jnp.ndarray] = None  # () int32
 
 
 def init_hypers(n_dims):
@@ -112,9 +114,15 @@ def fit_gp(x, y, mask, kind="matern52", n_steps=50, lr=0.08, init=None,
     loss_grad = jax.value_and_grad(_neg_mll)
 
     def step(carry, _):
-        hyp, opt = carry
+        hyp, opt, nonfinite = carry
         loss, grads = loss_grad(hyp, kind, x, y_norm, mask)
-        # A transiently ill-conditioned Cholesky must not poison the whole fit.
+        finite = jnp.isfinite(loss)
+        for g in jax.tree.leaves(grads):
+            finite &= jnp.all(jnp.isfinite(g))
+        nonfinite += (~finite).astype(jnp.int32)
+        # A transiently ill-conditioned Cholesky must not poison the whole
+        # fit; `nonfinite` counts the steps this zeroes (health field
+        # gp_fit_nonfinite).
         grads = jax.tree.map(jnp.nan_to_num, grads)
         updates, opt = optimizer.update(grads, opt)
         hyp = optax.apply_updates(hyp, updates)
@@ -128,9 +136,11 @@ def fit_gp(x, y, mask, kind="matern52", n_steps=50, lr=0.08, init=None,
             # otherwise drive noise to 0 and the f32 Cholesky off a cliff.
             log_noise=jnp.clip(hyp.log_noise, jnp.log(1e-4), jnp.log(1.0)),
         )
-        return (hyp, opt), loss
+        return (hyp, opt, nonfinite), loss
 
-    (hypers, _), _losses = jax.lax.scan(step, (hypers, opt_state), None, length=n_steps)
+    (hypers, _, nonfinite), _losses = jax.lax.scan(
+        step, (hypers, opt_state, jnp.zeros((), jnp.int32)), None, length=n_steps
+    )
 
     k = _masked_kernel(kind, x, mask, hypers)
     chol = jnp.linalg.cholesky(k)
@@ -144,7 +154,7 @@ def fit_gp(x, y, mask, kind="matern52", n_steps=50, lr=0.08, init=None,
     mll = -0.5 * (quad + logdet) / n
     return GPState(
         x=x, y=y, mask=mask, hypers=hypers, chol=chol, alpha=alpha,
-        y_mean=y_mean, y_std=y_std, mll=mll,
+        y_mean=y_mean, y_std=y_std, mll=mll, fit_nonfinite=nonfinite,
     )
 
 

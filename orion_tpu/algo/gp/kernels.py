@@ -3,7 +3,12 @@
 Pairwise distances are computed via the ||a-b||^2 = ||a||^2 + ||b||^2 - 2ab
 expansion so the dominant cost is one (n, d) x (d, m) matmul that XLA tiles
 onto the systolic array, instead of an O(n*m*d) broadcast-subtract that would
-be HBM-bandwidth-bound.
+be HBM-bandwidth-bound.  Both inputs are first shifted by one common centre
+(the mean of ``xb``'s rows): the expansion's float32 rounding error scales
+with ||a||^2, not with the distance, so on a fit set packed tightly far from
+the origin (a trust region closing on its incumbent) the uncentred form
+rounds near-neighbour distances to noise and K stops being positive
+definite.  Centred, ||a||^2 is the set's own spread.
 """
 
 import jax
@@ -13,12 +18,17 @@ import jax.numpy as jnp
 def sq_dists(xa, xb, inv_lengthscales):
     """Squared scaled euclidean distances, matmul-dominant.
 
+    The rows are centred on ``xb``'s mean first (module docstring): the
+    shift leaves every distance as it is and keeps the cancellation in
+    aa+bb-2ab at the scale of the points' spread.
+
     The cross term MUST run at full f32 precision: TPU's default bf16 matmul
     loses ~0.4% relative, which after the aa+bb-2ab cancellation shows up as
     k(x,x) != amplitude and an indefinite kernel matrix.
     """
-    a = xa * inv_lengthscales
-    b = xb * inv_lengthscales
+    centre = jnp.mean(xb, axis=0)
+    a = (xa - centre) * inv_lengthscales
+    b = (xb - centre) * inv_lengthscales
     aa = jnp.sum(a * a, axis=-1)[:, None]
     bb = jnp.sum(b * b, axis=-1)[None, :]
     cross = jnp.matmul(a, b.T, precision=jax.lax.Precision.HIGHEST)

@@ -324,6 +324,9 @@ class TPUBO(BaseAlgorithm):
         # dict rebuild and the 16-tuple prep-key probe entirely.
         self._step_kw_cache = None
         self._prep_token = PlanPrepToken()
+        # Fit steps of the last dispatched round, held while telemetry is on
+        # until the host holds its rows (_materialize_batch counts them).
+        self._fit_steps_uncounted = None
 
     # Naive-copy sharing (base __deepcopy__): the mesh handle and the
     # prewarmer's threads/locks are not copyable (and the jit cache they
@@ -484,11 +487,14 @@ class TPUBO(BaseAlgorithm):
                 # new observation) runs in-jit over the masked device y, so
                 # nothing history-sized crosses the boundary here either.
                 x_dev, y_dev, mask_dev, _ = self._hist.fit_view()
-            return make_fused_plan(
+            plan = make_fused_plan(
                 self.next_key(), x_dev, y_dev, mask_dev, best_x,
                 self._gp_state, num, tr_length=self._tr_length,
                 prep_token=self._prep_token, **step_kw,
             )
+            if TELEMETRY.enabled:
+                self._fit_steps_uncounted = plan.statics["fit_steps"]
+            return plan
 
     def consume_fused_step(self, state):
         """Accept the GPState a fused-plan dispatch produced (warm-start
@@ -509,6 +515,18 @@ class TPUBO(BaseAlgorithm):
         rows, state = run_fused_plan(plan, prewarmer=self._prewarmer)
         self.consume_fused_step(state)
         return rows
+
+    def _materialize_batch(self, cube, num=None):
+        batch = super()._materialize_batch(cube, num)
+        steps, self._fit_steps_uncounted = self._fit_steps_uncounted, None
+        if steps is not None and TELEMETRY.enabled:
+            # The rows are on the host, so the step (and its fit) is done:
+            # reading the fit's count transfers ready data, it does not sync.
+            TELEMETRY.count("gp.fit.steps", steps)
+            TELEMETRY.count(
+                "gp.fit.nonfinite_steps", int(self._gp_state.fit_nonfinite)
+            )
+        return batch
 
     # --- health -------------------------------------------------------------
     def health_record(self):
@@ -1751,6 +1769,7 @@ def _suggest_step(
                 jnp.mean(ls),
                 jnp.max(ls),
                 jnp.exp(state.hypers.log_noise),
+                state.fit_nonfinite.astype(jnp.float32),
                 jnp.max(ei_stats),
                 jnp.mean(ei_stats),
                 n_unique / q,

@@ -63,6 +63,9 @@ DEFAULT_FLIGHT_CAPACITY = 512
 #:   GP treats as pure noise);
 #: - ``gp_noise``: fitted noise level (rising toward its ceiling = the
 #:   objective looks irreproducible to the model);
+#: - ``gp_fit_nonfinite``: Adam steps of the fit whose loss or gradient was
+#:   non-finite (zeroed, so the step left the hyper-parameters where they
+#:   were; every step non-finite = the fit froze at its warm start);
 #: - ``acq_ei_max`` / ``acq_ei_mean``: expected improvement over the
 #:   candidate pool (both ~0 = acquisition has flattened: converged, or
 #:   the incumbent is unattainable under the current fit);
@@ -75,6 +78,7 @@ DEVICE_HEALTH_FIELDS = (
     "gp_ls_mean",
     "gp_ls_max",
     "gp_noise",
+    "gp_fit_nonfinite",
     "acq_ei_max",
     "acq_ei_mean",
     "q_unique_frac",
